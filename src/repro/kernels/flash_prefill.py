@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -117,7 +116,7 @@ def flash_prefill(q, k, v, *, block_q: int = 256, block_k: int = 512,
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
             pltpu.VMEM((block_q, hd), jnp.float32),  # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -242,7 +241,7 @@ def fused_paged_flash_prefill(q, pool_k, pool_v, phys, q_offset, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n_kv, rows, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(phys, q_offset, qt, pool_k, pool_v)

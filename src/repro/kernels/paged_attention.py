@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.paging import resolve_physical_blocks
 
 NEG_INF = -1e30
@@ -128,7 +127,7 @@ def fused_paged_decode_attention(q, pool_k, pool_v, phys, seq_lens, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n_kv, group, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(phys, seq_lens, qt, pool_k, pool_v)

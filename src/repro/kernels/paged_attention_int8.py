@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.paging import resolve_physical_blocks
 
 NEG_INF = -1e30
@@ -120,7 +119,7 @@ def paged_decode_attention_int8(q, pool_k, pool_v, pool_sk, pool_sv,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, n_kv, group, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(phys, seq_lens, qt, pool_k, pool_v, sk, sv)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,
@@ -40,33 +39,44 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,
     d_skip = d_ref[0, 0]                         # [1, 1] f32
 
     neg_a = -jnp.exp(a[0, 0])
-    dA = dt[:, 0] * neg_a                        # [Q] log-decay
-    l = jnp.cumsum(dA)                           # [Q]
+    dA = dt * neg_a                              # [Q, 1] log-decay
     xdt = x * dt                                 # [Q, P]
+
+    # cumulative decay l_i = Σ_{j≤i} dA_j as a lower-triangular matmul
+    # (Mosaic has no cumsum), once as a column and once as a row so the
+    # pairwise differences need no vector transpose
+    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (li >= lj).astype(jnp.float32)
+    exact = jax.lax.Precision.HIGHEST
+    l = jax.lax.dot_general(tril, dA, (((1,), (0,)), ((), ())),
+                            precision=exact,
+                            preferred_element_type=jnp.float32)   # [Q,1]
+    l_row = jax.lax.dot_general(dA, tril, (((0,), (1,)), ((), ())),
+                                precision=exact,
+                                preferred_element_type=jnp.float32)  # [1,Q]
 
     # intra-chunk: scores[i,j] = (C_i·B_j)·exp(l_i − l_j), i ≥ j
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Q,Q]
-    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = l[:, None] - l[None, :]
     # mask before exp (overflow above the diagonal — see mamba2.py)
-    decay = jnp.exp(jnp.where(li >= lj, seg, -1e30))
+    decay = jnp.exp(jnp.where(li >= lj, l - l_row, -1e30))
     y_intra = jax.lax.dot_general(cb * decay, xdt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
     # inter-chunk: y_inter[i] = exp(l_i) · (C_i · S_prev)
     s_prev = state_ref[...]                      # [P, N]
-    y_inter = jnp.exp(l)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(l) * jax.lax.dot_general(
         C, s_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)      # [Q, P]
 
     y_ref[0, 0] = (y_intra + y_inter + d_skip[0, 0] * x).astype(y_ref.dtype)
 
     # state update: S ← exp(Σ dA)·S_prev + Σ_j exp(l_last − l_j)·x_j ⊗ B_j
-    w = jnp.exp(l[-1] - l)                       # [Q]
-    s_new = s_prev * jnp.exp(l[-1]) + jax.lax.dot_general(
-        xdt * w[:, None], B, (((0,), (0,)), ((), ())),
+    l_last = jnp.sum(dA)                         # scalar: l at the chunk end
+    w = jnp.exp(l_last - l)                      # [Q, 1]
+    s_new = s_prev * jnp.exp(l_last) + jax.lax.dot_general(
+        xdt * w, B, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)      # [P, N]
     state_ref[...] = s_new
 
@@ -119,7 +129,7 @@ def ssd_scan(x, dt, a_log, B, C, d_skip, *, chunk: int = 256,
             jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, dtt, a2, Bt, Ct, d2)

@@ -1,4 +1,6 @@
 import os
+# placeholder-device tool: 512 CPU devices, and never the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh).

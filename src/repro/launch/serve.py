@@ -35,6 +35,7 @@ from repro.core.placement import (Mesh, Placement, load_placement, place,
                                   save_placement)
 from repro.core.workload import (poisson_trace, power_law_rates,
                                  shared_prefix_trace)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.driver import (TickCostModel, build_unit_from_specs,
                                   requests_from_workload, serve_workload,
                                   units_from_placement)
@@ -179,6 +180,7 @@ def main() -> int:
                     help="estimated/planned rate ratio that arms the "
                          "re-plan trigger (sustained for 2 windows)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ---- scalar sanity (a bad flag should die here, not as an
     # assertion three layers down in the allocator) ---------------------

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.train import checkpoint as ckpt_lib
 from repro.train.data import DataConfig, synth_batch
@@ -36,6 +37,7 @@ def main() -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch) if args.full\
         else configs.get_reduced(args.arch)

@@ -45,11 +45,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.config import BLOCK_TOKENS, replace
+from repro.config import BLOCK_TOKENS, ModelConfig, replace
 from repro.core.placement import Placement
 from repro.core.workload import Workload
-from repro.models.transformer import init_params
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, Request, init_stacked_params
 from repro.serving.faults import FaultInjector, RecoveryCostModel
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
@@ -343,6 +342,22 @@ def requests_from_workload(wl: Workload, engines: Dict[str, Engine],
 # ---------------------------------------------------------------------------
 # placement → runtime bridge
 # ---------------------------------------------------------------------------
+def unit_head_dim(cfgs: Sequence[ModelConfig]) -> int:
+    """Head-block width of a unit's pool: the head_dim its attention
+    models share.  Attention-free models hold no KV and do not count
+    (a unit of them alone keeps width 64, which only sizes its SSM
+    quota unit).  The pool is grouped by one head_dim, so attention
+    models of different head_dims cannot share a unit."""
+    dims = sorted({c.hd for c in cfgs if not c.attn_free})
+    if len(dims) > 1:
+        raise ValueError(
+            "a unit pools KV at one head_dim, but its attention models "
+            "have " + ", ".join(f"{c.name}: {c.hd}" for c in cfgs
+                                if not c.attn_free)
+            + " — colocate models of one head_dim per unit")
+    return dims[0] if dims else 64
+
+
 def build_unit_from_specs(specs: Sequence[Tuple[str, str, float]],
                           pool_blocks: int = 200_000, max_slots: int = 4,
                           chunk_tokens: int = 0, seed: int = 0,
@@ -369,19 +384,33 @@ def build_unit_from_specs(specs: Sequence[Tuple[str, str, float]],
     (DESIGN.md §13): repeated prompt prefixes are adopted from cache
     and skip their prefill chunks.  Needs ``chunk_tokens`` — the
     whole-prompt prefill path cannot resume mid-prompt.
+
+    ``reduced=False`` builds the published configs: weights and pool
+    in bfloat16 (the reduced CPU variants keep float32), the pool at
+    the head_dim of the unit's attention models (``unit_head_dim``),
+    and each engine's weights built directly in their stacked layout,
+    so building a unit holds no second copy of any model's weights.
+    Every view's quota is floored at one sequence's charge, so each
+    LLM can admit a request whatever its rate share.
     """
     assert specs, "a unit needs at least one (name, arch, rate) spec"
     assert not (prefix_cache and not chunk_tokens),\
         "prefix_cache requires chunked prefill (chunk_tokens > 0)"
-    pool = UnifiedKVPool(pool_blocks, 64, dtype=jnp.float32,
+    cfgs = [replace(configs.get_reduced(arch) if reduced
+                    else configs.get(arch), name=name)
+            for name, arch, _ in specs]
+    # published widths serve in bfloat16 (weights and pool); the reduced
+    # CPU variants keep float32
+    dtype = jnp.float32 if reduced else jnp.bfloat16
+    pool = UnifiedKVPool(pool_blocks, unit_head_dim(cfgs), dtype=dtype,
                          prefix_cache=prefix_cache)
     rate_sum = sum(max(r, 0.0) for _, _, r in specs)
     min_quota = max(pool_blocks // (8 * len(specs)), 1)
     engines: Dict[str, Engine] = {}
-    for i, (name, arch, rate) in enumerate(specs):
-        cfg = configs.get_reduced(arch) if reduced else configs.get(arch)
-        cfg = replace(cfg, name=name)
-        params = init_params(jax.random.PRNGKey(seed + i), cfg, jnp.float32)
+    for i, ((name, _, rate), cfg) in enumerate(zip(specs, cfgs)):
+        # built stacked: the device never holds a second weight copy
+        params = init_stacked_params(jax.random.PRNGKey(seed + i), cfg,
+                                     dtype)
         if policy == "fcfs":
             # the temporal baseline has no quotas (paper Fig. 9; the
             # simulator grants fcfs views the full capacity too) — the
@@ -393,8 +422,13 @@ def build_unit_from_specs(specs: Sequence[Tuple[str, str, float]],
                 else 1 / len(specs)
             quota = max(int(pool_blocks * share), min_quota)
         view = pool.register_model(cfg, quota)
+        # admissibility: a rate-proportional share can fall below one
+        # sequence's charge (an SSM state at published widths is ~27k
+        # head-blocks); floor it so every LLM admits at least one request
+        view.quota = max(view.quota, view.min_seq_blocks)
         engines[name] = Engine(cfg, params, view, max_slots=max_slots,
-                               chunk_tokens=chunk_tokens or None)
+                               chunk_tokens=chunk_tokens or None,
+                               stacked=True)
     return MuxScheduler(engines, pool, policy=policy, fused=fused,
                         sm_frac=sm_fracs, max_queue=max_queue,
                         shed_policy=shed_policy)

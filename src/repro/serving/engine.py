@@ -48,6 +48,7 @@ from repro.models import mamba2 as M2
 from repro.models import moe as MoE
 from repro.models.layers import (attn_qkv, causal_attention, lm_logits,
                                  mlp, rms_norm)
+from repro.models.transformer import init_params
 from repro.serving import cache_ops
 from repro.serving.kvcache import ModelCacheView
 
@@ -140,6 +141,24 @@ def tree_bytes(tree) -> int:
     return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
 
 
+def init_stacked_params(key, cfg: ModelConfig, dtype):
+    """Seeded weights built directly with the leading model axis M=1.
+
+    One jitted program writes every leaf straight into its ``[1, ...]``
+    buffer, so the device never holds an unstacked copy beside the
+    stacked one — at published widths each copy is several GiB.  Hand
+    the result to ``Engine(..., stacked=True)``."""
+    return _stacked_init(replace(cfg, name=""), jnp.dtype(dtype))(key)
+
+
+@lru_cache(maxsize=None)
+def _stacked_init(cfg_key: ModelConfig, dtype):
+    """One compiled init program per geometry and dtype (model names
+    do not shape the weights), shared by every unit that is built."""
+    return jax.jit(lambda k: jax.tree_util.tree_map(
+        lambda a: a[None], init_params(k, cfg_key, dtype)))
+
+
 def unique_tree_bytes(trees) -> int:
     """Bytes of the *distinct* buffers across several param trees.
 
@@ -228,13 +247,20 @@ class PrefillJob:
 
 
 class Engine:
-    """Inference engine for one LLM over the shared pool (CPU/XLA path)."""
+    """Inference engine for one LLM over the shared pool.
+
+    Its step programs are the jnp/XLA implementations below; they run
+    on any JAX backend, and at published widths on the TPU."""
 
     def __init__(self, cfg: ModelConfig, params, view: ModelCacheView,
                  max_slots: int = 8, max_blocks_per_seq: int = 64,
                  rng_seed: int = 0, chunk_tokens: Optional[int] = None,
-                 clock=time.perf_counter):
-        """``chunk_tokens``: enable CHUNKED PREFILL (beyond-paper —
+                 clock=time.perf_counter, *, stacked: bool = False):
+        """``stacked``: ``params`` already carries the leading M=1
+        model axis (``init_stacked_params``, or another engine's
+        private tree) and is adopted as is, without a copy.
+
+        ``chunk_tokens``: enable CHUNKED PREFILL (beyond-paper —
         Sarathi-style): prompts are processed ``chunk_tokens`` at a
         time, one chunk per scheduler tick, so colocated LLMs' decode
         jobs interleave between chunks and a long prompt cannot
@@ -297,8 +323,8 @@ class Engine:
         # a FusedGroup adopts this engine (``adopt_stacked``) the tree
         # is swapped for the group's shared stack and the private copy
         # is freed, with no change to any step path.
-        self.params = jax.tree_util.tree_map(lambda a: jnp.asarray(a)[None],
-                                             params)
+        self.params = params if stacked else jax.tree_util.tree_map(
+            lambda a: jnp.asarray(a)[None], params)
         self.model_index = 0
         self._prefill_fn = jitted_step("prefill", self.cfg_key)
         self._decode_fn = jitted_step("decode", self.cfg_key)
@@ -430,11 +456,9 @@ class Engine:
         overcommits the quota."""
         if not self.free_slots():
             return False
-        # available_blocks counts evictable prefix-cache inventory —
-        # cached blocks are disposable and must never starve admission
-        return self.lifetime_blocks(req) + pending_blocks <= min(
-            self.view.quota_headroom(),
-            self.pool.available_blocks())
+        need = self.lifetime_blocks(req) + pending_blocks
+        return (need <= self.view.quota_headroom()
+                and self.view.fits_arena(need))
 
     # ------------------------------------------------------------------
     def prefill(self, reqs: List[Request]) -> int:
@@ -1186,4 +1210,8 @@ def jitted_step(kind: str, cfg_key: ModelConfig):
     colocated instances of one architecture recompile identical
     programs N times."""
     impl, donate = _IMPL_TABLE[kind]
-    return jax.jit(partial(impl, cfg=cfg_key), donate_argnums=donate)
+    # keep the impl's name on the partial: jit names the program (and
+    # its compile events and profiler spans) after it
+    step = partial(impl, cfg=cfg_key)
+    step.__name__ = impl.__name__
+    return jax.jit(step, donate_argnums=donate)

@@ -412,12 +412,24 @@ class ModelCacheView:
     def quota_headroom(self) -> int:
         return self.quota - self.used
 
+    @property
+    def min_seq_blocks(self) -> int:
+        """Charge of the smallest sequence: one token-block of KV plus
+        the SSM state — the least quota that admits one request."""
+        return self.group_size + self._ssm_blocks_per_seq
+
+    def fits_arena(self, blocks: int) -> bool:
+        """Whether ``blocks`` of this view's charge can be placed in
+        the arena.  A view without KV (pure SSM) allocates no arena
+        blocks: its state lives in engine arrays and is charged to the
+        quota only.  ``available_blocks`` (not raw free blocks) counts
+        prefix-cache blocks, which are evicted on demand — otherwise a
+        full cache would starve admission forever."""
+        return self.group_size == 0 or blocks <= self.pool.available_blocks()
+
     def can_append(self, seq_id: int, n_tokens: int) -> bool:
-        # available_blocks (not raw free_blocks): prefix-cache blocks
-        # are disposable and evicted on demand, so admission may count
-        # them — otherwise a full cache would starve admission forever
-        return self._blocks_needed(seq_id, n_tokens) <= min(
-            self.quota_headroom(), self.pool.available_blocks())
+        need = self._blocks_needed(seq_id, n_tokens)
+        return need <= self.quota_headroom() and self.fits_arena(need)
 
     def _blocks_needed(self, seq_id: int, n_tokens: int) -> int:
         sc = self.seqs.get(seq_id)
@@ -782,7 +794,8 @@ class UnifiedKVPool:
         v_lo, v_hi = self.views[lo], self.views[hi]
         spare = v_lo.quota - v_lo.used
         move = min(spare // 2, self.n_head_blocks // 8)
-        if move > 0 and v_lo.quota - move >= min_quota:
+        if move > 0 and v_lo.quota - move >= max(min_quota,
+                                                 v_lo.min_seq_blocks):
             v_lo.quota -= move
             v_hi.quota += move
 

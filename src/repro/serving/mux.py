@@ -578,10 +578,12 @@ class MuxScheduler:
         quota = eng.view.quota
         self.pool.unregister_model(name)
         view = self.pool.register_model(eng.cfg, quota)
-        params = jax.tree_util.tree_map(lambda a: a[0], eng.params)
-        fresh = Engine(eng.cfg, params, view, max_slots=eng.max_slots,
+        # remove_engine left the dead engine on its private [1, ...]
+        # tree: the rebuilt engine adopts those buffers without a copy
+        fresh = Engine(eng.cfg, eng.params, view, max_slots=eng.max_slots,
                        max_blocks_per_seq=eng.max_blocks,
-                       chunk_tokens=eng.chunk_tokens, clock=self.clock)
+                       chunk_tokens=eng.chunk_tokens, clock=self.clock,
+                       stacked=True)
         for r in evicted:
             r.requeues += 1
         carried: List[Request] = []

@@ -19,6 +19,8 @@ def _run(args, timeout=480):
                           text=True, timeout=timeout,
                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
                                "JAX_PLATFORMS": "cpu",
+                               # tests run without a persistent cache
+                               "JAX_ENABLE_COMPILATION_CACHE": "false",
                                "HOME": "/tmp"})
 
 
