@@ -29,9 +29,8 @@ import numpy as np
 
 from repro import configs
 from repro.config import replace
-from repro.models.transformer import init_params
 from repro.serving.engine import (TRACE_COUNTS, Engine, Request,
-                                  unique_tree_bytes)
+                                  init_stacked_params, unique_tree_bytes)
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
 
@@ -56,7 +55,8 @@ def _build(n_models: int, fused: bool, arch: str = "qwen2-7b",
     engines = {}
     for i in range(n_models):
         cfg = replace(base, name=f"llm{i}")
-        params = init_params(jax.random.PRNGKey(i), cfg, jnp.float32)
+        params = init_stacked_params(jax.random.PRNGKey(i), cfg,
+                                     jnp.float32)
         view = pool.register_model(cfg, pool_blocks // n_models)
         engines[cfg.name] = Engine(cfg, params, view, max_slots=max_slots,
                                    chunk_tokens=CHUNK_TOKENS,

@@ -46,7 +46,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
 # --- the unit (phase b) ----------------------------------------------------
-SEED = 1                   # trace seed: 12 requests (9 phi-3, 3 mamba2)
+# Trace seed: 12 requests, 6 per LLM; two pairs of mamba2 requests
+# arrive 32 ms apart, so both of its slots serve at once.
+SEED = 7
 ARCHS = ("phi-3-vision-4.2b", "mamba2-2.7b")   # most popular first
 MAX_RATE = 2.0             # req/s of the most popular LLM
 ALPHA = 1.0                # power-law popularity exponent
@@ -80,11 +82,16 @@ LOGIT_TOL_FACTOR = 2.0
 LOGIT_TOL_FLOOR = 2.0 ** -8
 
 # --- kernels (phase d) -----------------------------------------------------
-# Kernel and oracle read the same bf16 inputs and accumulate in float32;
-# the kernel rounds its output to bf16 (2^-9 relative) and may run its
-# matmuls as single bf16 MXU passes (2^-9 relative per product).  Outputs
-# are O(1), so 2e-2 of the oracle's scale is ~5x that error.
-KERNEL_TOL = 2e-2          # max|kernel − oracle| / max(1, max|oracle|)
+# Kernel and oracle read the same bf16 inputs and accumulate in float32.
+# The kernel writes bf16, so rounding alone moves an output by up to 2^-8
+# (3.9e-3) of the largest value in its batch row, and it may run its
+# matmuls as single bf16 MXU passes.  The error is taken per batch row (one
+# sequence) against that row's own largest oracle value, so the small
+# outputs of a long row are not measured against the large ones of a
+# short row.  Readings per row on a TPU v5e: 1.9e-3 (flash prefill) to
+# 4.9e-3 (SSD scan), near the rounding bound.  The limit is twice the
+# largest reading and 2.5 times the bound.
+KERNEL_TOL = 1e-2    # max over rows of max|kernel − oracle| / max|oracle|
 
 
 class CompileTimes:
@@ -315,13 +322,15 @@ def _check_kernel(name, kernel_fn, oracle_fn, args, interpret, tol):
            else a for a in args]
     with jax.default_matmul_precision("highest"):
         refs = jax.tree_util.tree_leaves(jax.jit(oracle_fn)(*f32))
-    worst = 0.0
+    errs = []
     for o, r in zip(outs, refs):
-        o, r = np.asarray(o, np.float32), np.asarray(r, np.float32)
-        worst = max(worst, float(np.abs(o - r).max())
-                    / max(1.0, float(np.abs(r).max())))
+        o, r = (np.asarray(a, np.float32).reshape(a.shape[0], -1)
+                for a in (o, r))
+        errs.append(np.abs(o - r).max(1) / np.abs(r).max(1))
+    worst = float(np.max(np.concatenate(errs)))      # a NaN stays NaN
     say(f"[d] {name}: {'Mosaic-compiled' if compiled else 'interpreted'}, "
-        f"max err {worst:.5g} of the oracle's scale (tol {tol})")
+        f"max err {worst:.5g} of its row's largest oracle value "
+        f"(tol {tol})")
     if not np.isfinite(worst) or worst > tol:
         fail(f"{name}: off its oracle by {worst:.5g} > {tol}")
 

@@ -11,8 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.models.transformer import init_params
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, Request, init_stacked_params
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
 
@@ -25,7 +24,7 @@ def build(policy: str):
     engines = {}
     for i, a in enumerate(ARCHS):
         cfg = configs.get_reduced(a)
-        params = init_params(jax.random.PRNGKey(i), cfg, jnp.float32)
+        params = init_stacked_params(jax.random.PRNGKey(i), cfg, jnp.float32)
         view = pool.register_model(cfg, 100_000)
         engines[cfg.name] = Engine(cfg, params, view, max_slots=2)
     return MuxScheduler(engines, pool, policy=policy), pool

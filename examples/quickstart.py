@@ -11,8 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.models.transformer import forward, init_params
-from repro.serving.engine import Engine, Request
+from repro.models.transformer import forward
+from repro.serving.engine import Engine, Request, init_stacked_params
 from repro.serving.kvcache import UnifiedKVPool
 
 
@@ -20,7 +20,8 @@ def main():
     cfg = configs.get_reduced("qwen2-7b")
     print(f"model: {cfg.name} ({cfg.n_layers}L d={cfg.d_model} "
           f"h={cfg.n_heads}/{cfg.n_kv_heads})")
-    params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    # weights carry a leading model axis (M=1): the engine's layout
+    params = init_stacked_params(jax.random.PRNGKey(0), cfg, jnp.float32)
 
     # the unified head-wise KV pool (paper §3.4) + one model view
     pool = UnifiedKVPool(n_head_blocks=100_000, head_dim=cfg.hd,
@@ -40,8 +41,9 @@ def main():
 
     # sanity: greedy generation by full recompute must match exactly
     seq = list(prompt)
+    one = jax.tree_util.tree_map(lambda a: a[0], params)
     for _ in range(8):
-        logits, _ = forward(params, cfg, jnp.asarray([seq]), remat=False)
+        logits, _ = forward(one, cfg, jnp.asarray([seq]), remat=False)
         seq.append(int(jnp.argmax(logits[0, -1])))
     assert req.output == seq[len(prompt):], "engine must match recompute"
     print("matches full-recompute greedy decoding ✓")

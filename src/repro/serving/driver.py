@@ -345,9 +345,9 @@ def requests_from_workload(wl: Workload, engines: Dict[str, Engine],
 def unit_head_dim(cfgs: Sequence[ModelConfig]) -> int:
     """Head-block width of a unit's pool: the head_dim its attention
     models share.  Attention-free models hold no KV and do not count
-    (a unit of them alone keeps width 64, which only sizes its SSM
-    quota unit).  The pool is grouped by one head_dim, so attention
-    models of different head_dims cannot share a unit."""
+    (a unit of them alone keeps width 64; its arena goes unused).  The
+    pool is grouped by one head_dim, so attention models of different
+    head_dims cannot share a unit."""
     dims = sorted({c.hd for c in cfgs if not c.attn_free})
     if len(dims) > 1:
         raise ValueError(
@@ -390,8 +390,6 @@ def build_unit_from_specs(specs: Sequence[Tuple[str, str, float]],
     the head_dim of the unit's attention models (``unit_head_dim``),
     and each engine's weights built directly in their stacked layout,
     so building a unit holds no second copy of any model's weights.
-    Every view's quota is floored at one sequence's charge, so each
-    LLM can admit a request whatever its rate share.
     """
     assert specs, "a unit needs at least one (name, arch, rate) spec"
     assert not (prefix_cache and not chunk_tokens),\
@@ -422,13 +420,8 @@ def build_unit_from_specs(specs: Sequence[Tuple[str, str, float]],
                 else 1 / len(specs)
             quota = max(int(pool_blocks * share), min_quota)
         view = pool.register_model(cfg, quota)
-        # admissibility: a rate-proportional share can fall below one
-        # sequence's charge (an SSM state at published widths is ~27k
-        # head-blocks); floor it so every LLM admits at least one request
-        view.quota = max(view.quota, view.min_seq_blocks)
         engines[name] = Engine(cfg, params, view, max_slots=max_slots,
-                               chunk_tokens=chunk_tokens or None,
-                               stacked=True)
+                               chunk_tokens=chunk_tokens or None)
     return MuxScheduler(engines, pool, policy=policy, fused=fused,
                         sm_frac=sm_fracs, max_queue=max_queue,
                         shed_policy=shed_policy)

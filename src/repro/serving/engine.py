@@ -146,8 +146,8 @@ def init_stacked_params(key, cfg: ModelConfig, dtype):
 
     One jitted program writes every leaf straight into its ``[1, ...]``
     buffer, so the device never holds an unstacked copy beside the
-    stacked one — at published widths each copy is several GiB.  Hand
-    the result to ``Engine(..., stacked=True)``."""
+    stacked one — at published widths each copy is several GiB.  This
+    is the tree ``Engine`` takes."""
     return _stacked_init(replace(cfg, name=""), jnp.dtype(dtype))(key)
 
 
@@ -255,10 +255,10 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, view: ModelCacheView,
                  max_slots: int = 8, max_blocks_per_seq: int = 64,
                  rng_seed: int = 0, chunk_tokens: Optional[int] = None,
-                 clock=time.perf_counter, *, stacked: bool = False):
-        """``stacked``: ``params`` already carries the leading M=1
-        model axis (``init_stacked_params``, or another engine's
-        private tree) and is adopted as is, without a copy.
+                 clock=time.perf_counter):
+        """``params`` carries the leading M=1 model axis
+        (``init_stacked_params``, or another engine's private tree) and
+        is adopted as is, without a copy.
 
         ``chunk_tokens``: enable CHUNKED PREFILL (beyond-paper —
         Sarathi-style): prompts are processed ``chunk_tokens`` at a
@@ -323,8 +323,7 @@ class Engine:
         # a FusedGroup adopts this engine (``adopt_stacked``) the tree
         # is swapped for the group's shared stack and the private copy
         # is freed, with no change to any step path.
-        self.params = params if stacked else jax.tree_util.tree_map(
-            lambda a: jnp.asarray(a)[None], params)
+        self.params = params
         self.model_index = 0
         self._prefill_fn = jitted_step("prefill", self.cfg_key)
         self._decode_fn = jitted_step("decode", self.cfg_key)
@@ -439,13 +438,11 @@ class Engine:
         return [i for i, r in enumerate(self.slots) if r is not None]
 
     def lifetime_blocks(self, req: Request) -> int:
-        """Head-blocks this request needs over its whole lifetime
-        (prompt + max_new tokens, plus SSM state pages)."""
+        """Head-blocks of KV this request needs over its whole
+        lifetime (prompt + max_new tokens); SSM state takes a slot and
+        no blocks."""
         total = len(req.prompt) + req.max_new_tokens
-        blocks = -(-total // BLOCK_TOKENS) * self.view.group_size
-        if self.cfg.ssm:
-            blocks += self.view._ssm_blocks_per_seq
-        return blocks
+        return -(-total // BLOCK_TOKENS) * self.view.group_size
 
     def can_admit(self, req: Request, pending_blocks: int = 0) -> bool:
         """Whether the request's whole-lifetime quota fits the current
@@ -456,9 +453,11 @@ class Engine:
         overcommits the quota."""
         if not self.free_slots():
             return False
-        need = self.lifetime_blocks(req) + pending_blocks
-        return (need <= self.view.quota_headroom()
-                and self.view.fits_arena(need))
+        # available_blocks counts evictable prefix-cache inventory —
+        # cached blocks are disposable and must never starve admission
+        return self.lifetime_blocks(req) + pending_blocks <= min(
+            self.view.quota_headroom(),
+            self.pool.available_blocks())
 
     # ------------------------------------------------------------------
     def prefill(self, reqs: List[Request]) -> int:

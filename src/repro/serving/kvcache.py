@@ -380,8 +380,10 @@ class ModelCacheView:
 
     Tracks quota (head-blocks) granted by ADBS and per-sequence block
     tables.  ``group_size = n_layers × n_kv_heads`` head-blocks per
-    token block (attention models); SSM models have group_size 0 and a
-    fixed per-seq state cost (accounted against quota, not the arena).
+    token block (attention models).  SSM state (pure SSM: group_size 0;
+    hybrid: beside its KV) lives in the engine's per-slot arrays, so
+    its sequences are bounded by the engine's slots and charged to
+    neither the quota nor the arena: the quota guards the arena only.
     """
 
     def __init__(self, cfg: ModelConfig, pool: "UnifiedKVPool", quota: int,
@@ -392,7 +394,6 @@ class ModelCacheView:
         self.used = 0
         self.group_size = cfg.n_attn_layers * cfg.n_kv_heads
         self.seqs: Dict[int, SeqCache] = {}
-        self._started: set = set()
         # prefix caching is a paged-attention feature: SSM/hybrid state
         # is a running summary of the whole prefix and cannot be
         # adopted block-wise, so those views never index
@@ -400,47 +401,24 @@ class ModelCacheView:
             PrefixIndex(self)
             if prefix_cache and self.group_size > 0 and not cfg.ssm
             else None)
-        # SSM quota accounting: state bytes expressed in head-block units
-        self._ssm_blocks_per_seq = 0
-        if cfg.ssm:
-            state_bytes = (cfg.n_ssm_layers * cfg.n_ssm_heads
-                           * cfg.ssm.head_dim * cfg.ssm.d_state * 4)
-            self._ssm_blocks_per_seq = max(
-                1, state_bytes // pool.head_block_bytes)
 
     # ---- quota ------------------------------------------------------
     def quota_headroom(self) -> int:
         return self.quota - self.used
 
-    @property
-    def min_seq_blocks(self) -> int:
-        """Charge of the smallest sequence: one token-block of KV plus
-        the SSM state — the least quota that admits one request."""
-        return self.group_size + self._ssm_blocks_per_seq
-
-    def fits_arena(self, blocks: int) -> bool:
-        """Whether ``blocks`` of this view's charge can be placed in
-        the arena.  A view without KV (pure SSM) allocates no arena
-        blocks: its state lives in engine arrays and is charged to the
-        quota only.  ``available_blocks`` (not raw free blocks) counts
-        prefix-cache blocks, which are evicted on demand — otherwise a
-        full cache would starve admission forever."""
-        return self.group_size == 0 or blocks <= self.pool.available_blocks()
-
     def can_append(self, seq_id: int, n_tokens: int) -> bool:
-        need = self._blocks_needed(seq_id, n_tokens)
-        return need <= self.quota_headroom() and self.fits_arena(need)
+        # available_blocks (not raw free_blocks): prefix-cache blocks
+        # are disposable and evicted on demand — otherwise a full cache
+        # would starve admission forever
+        return self._blocks_needed(seq_id, n_tokens) <= min(
+            self.quota_headroom(), self.pool.available_blocks())
 
     def _blocks_needed(self, seq_id: int, n_tokens: int) -> int:
         sc = self.seqs.get(seq_id)
         have = len(sc.bases) * BLOCK_TOKENS if sc else 0
         cur = sc.n_tokens if sc else 0
         need_tokens = max(0, cur + n_tokens - have)
-        n_groups = -(-need_tokens // BLOCK_TOKENS)
-        cost = n_groups * self.group_size
-        if sc is None and self.cfg.ssm:
-            cost += self._ssm_blocks_per_seq
-        return cost
+        return -(-need_tokens // BLOCK_TOKENS) * self.group_size
 
     # ---- allocation ---------------------------------------------------
     def share_prefix(self, seq_id: int, bases: List[int],
@@ -464,7 +442,6 @@ class ModelCacheView:
             self.pool.allocator.share(b, self.group_size)
         self.seqs[seq_id] = SeqCache(seq_id, list(bases), n_tokens,
                                      shared=len(bases))
-        self._started.add(seq_id)
         self.used += cost
         self.pool.used_by[self.cfg.name] = self.used
         return True
@@ -530,11 +507,7 @@ class ModelCacheView:
                 newly.append(base)
         sc.bases.extend(newly)
         sc.n_tokens += n_tokens
-        extra = n_groups * self.group_size
-        if seq_id not in self._started and self.cfg.ssm:
-            extra += self._ssm_blocks_per_seq
-        self._started.add(seq_id)
-        self.used += extra
+        self.used += n_groups * self.group_size
         self.pool.used_by[self.cfg.name] = self.used
         return True
 
@@ -544,11 +517,7 @@ class ModelCacheView:
             return
         for b in sc.bases:
             self.pool.allocator.free(b, self.group_size)
-        freed = len(sc.bases) * self.group_size
-        if self.cfg.ssm and seq_id in self._started:
-            freed += self._ssm_blocks_per_seq
-        self._started.discard(seq_id)
-        self.used -= freed
+        self.used -= len(sc.bases) * self.group_size
         self.pool.used_by[self.cfg.name] = self.used
 
     # ---- device-side tables -------------------------------------------
@@ -794,8 +763,7 @@ class UnifiedKVPool:
         v_lo, v_hi = self.views[lo], self.views[hi]
         spare = v_lo.quota - v_lo.used
         move = min(spare // 2, self.n_head_blocks // 8)
-        if move > 0 and v_lo.quota - move >= max(min_quota,
-                                                 v_lo.min_seq_blocks):
+        if move > 0 and v_lo.quota - move >= min_quota:
             v_lo.quota -= move
             v_hi.quota += move
 
@@ -894,11 +862,7 @@ def migrate_view(src: ModelCacheView, dst_pool: "UnifiedKVPool",
             new_bases.append(nb)
         dst.seqs[sid] = SeqCache(sid, new_bases, sc.n_tokens,
                                  shared=sc.shared)
-        used = len(new_bases) * dst.group_size
-        if cfg.ssm and sid in src._started:
-            used += dst._ssm_blocks_per_seq
-        dst.used += used
-    dst._started = set(src._started)
+        dst.used += len(new_bases) * dst.group_size
     dst.quota = max(dst.quota, dst.used)
     dst_pool.used_by[cfg.name] = dst.used
 

@@ -582,8 +582,7 @@ class MuxScheduler:
         # tree: the rebuilt engine adopts those buffers without a copy
         fresh = Engine(eng.cfg, eng.params, view, max_slots=eng.max_slots,
                        max_blocks_per_seq=eng.max_blocks,
-                       chunk_tokens=eng.chunk_tokens, clock=self.clock,
-                       stacked=True)
+                       chunk_tokens=eng.chunk_tokens, clock=self.clock)
         for r in evicted:
             r.requeues += 1
         carried: List[Request] = []

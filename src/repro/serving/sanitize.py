@@ -16,8 +16,8 @@ Checked laws, bottom-up:
   in-bounds, and disjoint from the live set; free + live covers the
   arena exactly.
 * pool/views — each view's ``used`` equals the recomputed charge of
-  its sequences (group blocks + SSM state units + shared-prefix full
-  charge); ``pool.used_by`` mirrors it; the allocator's ``used``
+  its sequences (group blocks, shared prefixes at full charge; SSM
+  state lives in engine slots and is charged nothing); ``pool.used_by`` mirrors it; the allocator's ``used``
   equals the sum of all holders (sequence charges + prefix-index
   refs); every sequence base and every prefix-index entry points at a
   live group; the device arrays match the arena size.
@@ -110,13 +110,10 @@ def allocator_errors(alloc) -> List[str]:
 def _view_charge(view) -> int:
     """Recompute what the view's sequences should be charged: group
     blocks per token-block (shared prefixes at FULL charge — the
-    DESIGN.md §13 COW policy) plus the SSM state footprint."""
-    charge = sum(len(sc.bases) for sc in view.seqs.values())\
+    DESIGN.md §13 COW policy).  SSM state lives in engine slots and
+    is charged nothing."""
+    return sum(len(sc.bases) for sc in view.seqs.values())\
         * view.group_size
-    if view.cfg.ssm:
-        started = sum(1 for sid in view.seqs if sid in view._started)
-        charge += started * view._ssm_blocks_per_seq
-    return charge
 
 
 def pool_errors(pool) -> List[str]:
@@ -151,10 +148,8 @@ def pool_errors(pool) -> List[str]:
                     errs.append(f"view {name} seq {sid}: base {base} "
                                 f"group holds dead blocks {dead[:4]}")
                     break
-        # arena holders: token-block bases (SSM state units live in
-        # the separate state arena, not the head-block allocator)
-        holders += sum(len(sc.bases) for sc in view.seqs.values())\
-            * view.group_size
+        # arena holders: token-block bases
+        holders += _view_charge(view)
         if view.prefix_index is not None:
             holders += view.prefix_index.held_blocks
             for _h, (base, _blk) in view.prefix_index.entries():

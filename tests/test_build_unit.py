@@ -1,7 +1,7 @@
 """The published-width build path of ``build_unit_from_specs`` at CPU
 size: the pool's head_dim comes from the colocated attention models,
 weights are built stacked (no second copy), an SSM state larger than
-its rate share still admits, the compile-cache helper's paths, and
+its rate share still fills every slot, the compile-cache helper's paths, and
 kernel dispatch that never falls back silently."""
 import os
 import subprocess
@@ -14,6 +14,8 @@ import pytest
 
 from repro import configs
 from repro.config import replace
+from repro.core.estimator import LLMSpec
+from repro.core.placement import Mesh, Placement
 from repro.core.workload import RequestSpec, Workload
 from repro.kernels import ops
 from repro.launch import compile_cache
@@ -21,8 +23,9 @@ from repro.models.transformer import init_params
 from repro.serving import driver
 from repro.serving.driver import (TickCostModel, build_unit_from_specs,
                                   serve_workload, unit_head_dim)
-from repro.serving.engine import Engine, init_stacked_params
+from repro.serving.engine import Engine, Request, init_stacked_params
 from repro.serving.kvcache import UnifiedKVPool
+from repro.serving.reconfig import MigrationExecutor
 
 
 def _patch_reduced(monkeypatch, **overrides):
@@ -32,6 +35,11 @@ def _patch_reduced(monkeypatch, **overrides):
     def get_reduced(arch):
         return replace(real(arch), **overrides.get(arch, {}))
     monkeypatch.setattr(driver.configs, "get_reduced", get_reduced)
+
+
+def _home_env():
+    """The caller's own HOME and TMPDIR, for a child process."""
+    return {k: os.environ[k] for k in ("HOME", "TMPDIR") if k in os.environ}
 
 
 def _trace(names, n_each=1, prompt=40, out=3):
@@ -67,28 +75,46 @@ def test_mixed_head_dims_in_one_unit_raise(monkeypatch):
                                        head_dim=128)]) == 128
 
 
-def test_ssm_state_above_its_rate_share_still_admits(monkeypatch):
-    # d_state 256: the state of one sequence is 128 head-blocks, above
-    # both the unpopular LLM's rate share and the pool-wide floor
-    # (pool_blocks // 16 = 32)
+@pytest.mark.parametrize("rebalance", [False, True])
+def test_ssm_state_above_its_rate_share_still_admits(monkeypatch, rebalance):
+    # d_state 256: one sequence's state is 1 MiB, four times the
+    # unpopular LLM's whole quota (32 head-blocks of 8 KiB), at startup
+    # and after a re-plan re-splits the quotas by rate
     mamba = configs.get_reduced("mamba2-2.7b")
     _patch_reduced(monkeypatch, **{"mamba2-2.7b": {
         "ssm": replace(mamba.ssm, d_state=256)}})
-    unit = build_unit_from_specs(
-        [("a", "qwen2-7b", 100.0), ("s", "mamba2-2.7b", 1.0)],
-        pool_blocks=512, max_slots=2, chunk_tokens=16)
-    view = unit.engines["s"].view
-    assert view._ssm_blocks_per_seq == 128 > 512 // 16
-    assert view.quota >= view.min_seq_blocks
-    # the state lives off-arena: a full arena does not block it
+    specs = [("a", "qwen2-7b", 100.0), ("s", "mamba2-2.7b", 1.0)]
+    unit = build_unit_from_specs(specs, pool_blocks=512, max_slots=2,
+                                 chunk_tokens=16)
+    if rebalance:
+        # the re-plan halves the popular LLM's rate
+        plan = Placement([Mesh(0, 1, [LLMSpec(unit.engines[n].cfg, r)
+                                      for n, r in (("a", 50.0), ("s", 1.0))])],
+                         0.0)
+        assert MigrationExecutor({0: unit}).rebalance_quotas(plan) > 0
+    eng = unit.engines["s"]
+    state = eng.ssm_state[:, 0].nbytes
+    assert state >= 4 * eng.view.quota * unit.pool.head_block_bytes
+    # the state lives in the engine's slots: it is charged nothing, so
+    # neither the quota nor a full arena holds it back
     arena = unit.pool.allocator
     held = arena.alloc(arena.free_blocks)
-    assert view.can_append(0, 8)
+    r0, r1 = (Request(i, "s", list(range(1, 41)), 3) for i in range(2))
+    assert eng.lifetime_blocks(r0) == 0
+    assert eng.can_admit(r1, pending_blocks=eng.lifetime_blocks(r0))
     arena.free(held, arena.n_blocks)
-    rep = serve_workload([unit], _trace(["a", "s"], n_each=2), seed=0,
-                         cost=TickCostModel(), watchdog_ticks=50)
-    assert rep.per_llm["s"].finished == rep.per_llm["s"].submitted == 2
-    assert rep.aggregate.shed == 0
+    # both slots serve at once
+    for i in range(2):
+        unit.submit(Request(i, "s", list(range(1, 41)), 3))
+    busy = 0
+    for _ in range(200):
+        if not unit.pending():
+            break
+        unit.tick()
+        busy = max(busy, sum(r is not None for r in eng.slots))
+    assert busy == 2
+    assert len(unit.stats.finished) == 2
+    assert unit.pool.views["s"].used == 0
 
 
 def test_stacked_weights_are_adopted_without_a_copy():
@@ -102,7 +128,7 @@ def test_stacked_weights_are_adopted_without_a_copy():
         np.testing.assert_allclose(np.asarray(s[0]), np.asarray(e),
                                    rtol=1e-6, atol=1e-7)
     pool = UnifiedKVPool(1024, cfg.hd, dtype=jnp.float32)
-    eng = Engine(cfg, stacked, pool.register_model(cfg, 1024), stacked=True)
+    eng = Engine(cfg, stacked, pool.register_model(cfg, 1024))
     assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(eng.params),
                                       jax.tree_util.tree_leaves(stacked)))
 
@@ -149,6 +175,6 @@ def test_importing_kernel_ops_initialises_no_backend():
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=120,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "JAX_PLATFORMS": "cpu", "HOME": "/tmp"})
+                            "JAX_PLATFORMS": "cpu", **_home_env()})
     assert r.returncode == 0, r.stderr[-2000:]
     assert r.stdout.strip() == "False"

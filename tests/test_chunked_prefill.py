@@ -11,11 +11,13 @@ from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
 
+from _weights import with_model_axis
+
 
 def _serve(cfg, params, prompts, chunk, max_new=4):
     pool = UnifiedKVPool(100_000, cfg.hd, dtype=jnp.float32)
     view = pool.register_model(cfg, 100_000)
-    eng = Engine(cfg, params, view, max_slots=len(prompts),
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=len(prompts),
                  chunk_tokens=chunk)
     reqs = [Request(i, cfg.name, p, max_new)
             for i, p in enumerate(prompts)]
@@ -54,8 +56,9 @@ def test_chunked_prefill_interleaves_decode():
     pool = UnifiedKVPool(200_000, 64, dtype=jnp.float32)
     va = pool.register_model(cfg_a, 100_000)
     vb = pool.register_model(cfg_b, 100_000)
-    eng_a = Engine(cfg_a, pa, va, max_slots=1, chunk_tokens=8)
-    eng_b = Engine(cfg_b, pb, vb, max_slots=1)
+    eng_a = Engine(cfg_a, with_model_axis(pa), va, max_slots=1,
+                   chunk_tokens=8)
+    eng_b = Engine(cfg_b, with_model_axis(pb), vb, max_slots=1)
     mux = MuxScheduler({cfg_a.name: eng_a, cfg_b.name: eng_b}, pool,
                        policy="adbs")
     rng = np.random.default_rng(2)

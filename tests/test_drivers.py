@@ -1,4 +1,5 @@
 """CLI drivers + engine↔Pallas integration."""
+import os
 import subprocess
 import sys
 
@@ -13,6 +14,8 @@ from repro.serving import cache_ops
 from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool
 
+from _weights import with_model_axis
+
 
 def _run(args, timeout=480):
     return subprocess.run([sys.executable, *args], capture_output=True,
@@ -21,7 +24,8 @@ def _run(args, timeout=480):
                                "JAX_PLATFORMS": "cpu",
                                # tests run without a persistent cache
                                "JAX_ENABLE_COMPILATION_CACHE": "false",
-                               "HOME": "/tmp"})
+                               **{k: os.environ[k] for k in ("HOME", "TMPDIR")
+                                  if k in os.environ}})
 
 
 def test_train_driver_cli():
@@ -70,7 +74,7 @@ def test_engine_pool_matches_pallas_kernel():
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
     pool = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
     view = pool.register_model(cfg, 50_000)
-    eng = Engine(cfg, params, view, max_slots=2)
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=2)
     rng = np.random.default_rng(0)
     reqs = [Request(i, cfg.name,
                     list(rng.integers(1, cfg.vocab_size, 10 + 3 * i)), 2)

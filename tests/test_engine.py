@@ -10,6 +10,8 @@ from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
 
+from _weights import with_model_axis
+
 
 def _engine(arch, quota=100_000, n_blocks=200_000, max_slots=4, seed=0):
     cfg = configs.get_reduced(arch)
@@ -17,7 +19,8 @@ def _engine(arch, quota=100_000, n_blocks=200_000, max_slots=4, seed=0):
     pool = UnifiedKVPool(n_blocks, cfg.hd if cfg.hd else 64,
                          dtype=jnp.float32)
     view = pool.register_model(cfg, quota)
-    return Engine(cfg, params, view, max_slots=max_slots), pool, cfg, params
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=max_slots)
+    return eng, pool, cfg, params
 
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "zamba2-1.2b", "mamba2-2.7b"])
@@ -87,8 +90,10 @@ def test_mux_scheduler_two_llms():
     pb = init_params(jax.random.PRNGKey(1), cfg_b, jnp.float32)
     va = pool.register_model(cfg_a, 100_000)
     vb = pool.register_model(cfg_b, 100_000)
-    engines = {cfg_a.name: Engine(cfg_a, pa, va, max_slots=2),
-               cfg_b.name: Engine(cfg_b, pb, vb, max_slots=2)}
+    engines = {cfg_a.name: Engine(cfg_a, with_model_axis(pa), va,
+                                  max_slots=2),
+               cfg_b.name: Engine(cfg_b, with_model_axis(pb), vb,
+                                  max_slots=2)}
     mux = MuxScheduler(engines, pool, policy="adbs")
     rng = np.random.default_rng(3)
     reqs = []
@@ -107,7 +112,7 @@ def test_mux_scheduler_two_llms():
     # isolation: serving alone gives the same tokens
     solo_pool = UnifiedKVPool(200_000, 64, dtype=jnp.float32)
     sv = solo_pool.register_model(cfg_a, 100_000)
-    solo = Engine(cfg_a, pa, sv, max_slots=2)
+    solo = Engine(cfg_a, with_model_axis(pa), sv, max_slots=2)
     q = Request(0, cfg_a.name, reqs[0].prompt, 3)
     solo.prefill([q])
     while not q.done:
@@ -127,7 +132,7 @@ def test_batch_admission_accounts_for_pending():
     pool = UnifiedKVPool(1000, cfg.hd, dtype=jnp.float32)
     view = pool.register_model(cfg, 12)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
-    eng = Engine(cfg, params, view, max_slots=2)
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=2)
     rng = np.random.default_rng(0)
     r1 = Request(0, cfg.name, list(rng.integers(1, 512, 20)), 8)
     r2 = Request(1, cfg.name, list(rng.integers(1, 512, 20)), 8)
@@ -146,7 +151,7 @@ def test_decode_quota_overcommit_rolls_back():
     pool = UnifiedKVPool(1000, cfg.hd, dtype=jnp.float32)
     view = pool.register_model(cfg, 12)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
-    eng = Engine(cfg, params, view, max_slots=2)
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=2)
     rng = np.random.default_rng(0)
     r1 = Request(0, cfg.name, list(rng.integers(1, 512, 14)), 8)
     r2 = Request(1, cfg.name, list(rng.integers(1, 512, 14)), 8)
@@ -162,7 +167,8 @@ def test_decode_quota_overcommit_rolls_back():
     assert pool.allocator.used == 0
     # no corruption: the stalled request's tokens match uncontended runs
     pool2 = UnifiedKVPool(1000, cfg.hd, dtype=jnp.float32)
-    eng2 = Engine(cfg, params, pool2.register_model(cfg, 1000),
+    eng2 = Engine(cfg, with_model_axis(params),
+                  pool2.register_model(cfg, 1000),
                   max_slots=2)
     for r in (r1, r2):
         q = Request(9, cfg.name, list(r.prompt), 8)
@@ -186,7 +192,7 @@ def test_decode_overcommit_hybrid_state_revert():
     # probe the quota analytically: admit r1, leave exactly one more
     # lifetime of headroom so r2 admits but their growth overcommits
     probe_pool = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
-    probe = Engine(cfg, params,
+    probe = Engine(cfg, with_model_axis(params),
                    probe_pool.register_model(cfg, 50_000), max_slots=2)
     pr = Request(0, cfg.name, list(p1), max_new)
     lifetime = probe.lifetime_blocks(pr)
@@ -196,8 +202,8 @@ def test_decode_overcommit_hybrid_state_revert():
     quota = used_p + lifetime
 
     pool = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
-    eng = Engine(cfg, params, pool.register_model(cfg, quota),
-                 max_slots=2)
+    eng = Engine(cfg, with_model_axis(params),
+                 pool.register_model(cfg, quota), max_slots=2)
     mux = MuxScheduler({cfg.name: eng}, pool, policy="adbs")
     r1 = Request(0, cfg.name, list(p1), max_new)
     r2 = Request(1, cfg.name, list(p2), max_new)
@@ -208,7 +214,8 @@ def test_decode_overcommit_hybrid_state_revert():
     assert pool.allocator.used == 0
     # outputs must match uncontended serving despite rollback/preempt
     pool2 = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
-    eng2 = Engine(cfg, params, pool2.register_model(cfg, 50_000),
+    eng2 = Engine(cfg, with_model_axis(params),
+                  pool2.register_model(cfg, 50_000),
                   max_slots=2)
     for r in (r1, r2):
         q = Request(9, cfg.name, list(r.prompt), max_new)
@@ -229,8 +236,10 @@ def test_quota_regrant_for_oversized_head_request():
     pb = init_params(jax.random.PRNGKey(1), cfg_b, jnp.float32)
     va = pool.register_model(cfg_a, 4)           # as if adapt shrank it
     vb = pool.register_model(cfg_b, 50_000)
-    engines = {cfg_a.name: Engine(cfg_a, pa, va, max_slots=2),
-               cfg_b.name: Engine(cfg_b, pb, vb, max_slots=2)}
+    engines = {cfg_a.name: Engine(cfg_a, with_model_axis(pa), va,
+                                  max_slots=2),
+               cfg_b.name: Engine(cfg_b, with_model_axis(pb), vb,
+                                  max_slots=2)}
     mux = MuxScheduler(engines, pool, policy="adbs")
     rng = np.random.default_rng(6)
     r = Request(0, cfg_a.name, list(rng.integers(1, 512, 14)), 8)
@@ -255,7 +264,7 @@ def test_stall_escape_preemption_unblocks_deadlock():
     pool = UnifiedKVPool(1000, cfg.hd, dtype=jnp.float32)
     view = pool.register_model(cfg, 12)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
-    eng = Engine(cfg, params, view, max_slots=2)
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=2)
     mux = MuxScheduler({cfg.name: eng}, pool, policy="adbs")
     rng = np.random.default_rng(1)
     ra = Request(0, cfg.name, list(rng.integers(1, 512, 14)), 28)
@@ -268,7 +277,8 @@ def test_stall_escape_preemption_unblocks_deadlock():
     assert pool.allocator.used == 0
     # the preempted request's restart must be output-identical
     pool2 = UnifiedKVPool(1000, cfg.hd, dtype=jnp.float32)
-    eng2 = Engine(cfg, params, pool2.register_model(cfg, 1000),
+    eng2 = Engine(cfg, with_model_axis(params),
+                  pool2.register_model(cfg, 1000),
                   max_slots=2)
     for r in (ra, rb):
         q = Request(9, cfg.name, list(r.prompt), r.max_new_tokens)
@@ -284,7 +294,8 @@ def test_mux_policies_drain(policy):
     pool = UnifiedKVPool(100_000, cfg.hd, dtype=jnp.float32)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
     view = pool.register_model(cfg, 100_000)
-    mux = MuxScheduler({cfg.name: Engine(cfg, params, view, max_slots=2)},
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=2)
+    mux = MuxScheduler({cfg.name: eng},
                        pool, policy=policy)
     rng = np.random.default_rng(0)
     for i in range(3):

@@ -13,6 +13,8 @@ from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool, fused_block_tables
 from repro.serving.mux import MuxScheduler
 
+from _weights import with_model_axis
+
 
 def _colocated(archs, fused, max_slots=2, quota=30_000, n_blocks=100_000):
     """Build a unit of colocated reduced engines (repeated archs get
@@ -23,7 +25,8 @@ def _colocated(archs, fused, max_slots=2, quota=30_000, n_blocks=100_000):
         cfg = replace(configs.get_reduced(a), name=f"m{i}")
         params = init_params(jax.random.PRNGKey(i), cfg, jnp.float32)
         view = pool.register_model(cfg, quota)
-        engines[cfg.name] = Engine(cfg, params, view, max_slots=max_slots)
+        engines[cfg.name] = Engine(cfg, with_model_axis(params), view,
+                                   max_slots=max_slots)
     return MuxScheduler(engines, pool, policy="adbs", fused=fused), pool
 
 
@@ -142,13 +145,14 @@ def test_fusion_signature_eligibility():
     pool = UnifiedKVPool(50_000, 64, dtype=jnp.float32)
     pt = init_params(jax.random.PRNGKey(0), cfg_t, jnp.float32)
     ps = init_params(jax.random.PRNGKey(1), cfg_s, jnp.float32)
-    et = Engine(cfg_t, pt, pool.register_model(cfg_t, 10_000))
-    es = Engine(cfg_s, ps, pool.register_model(cfg_s, 10_000))
+    et = Engine(cfg_t, with_model_axis(pt), pool.register_model(cfg_t, 10_000))
+    es = Engine(cfg_s, with_model_axis(ps), pool.register_model(cfg_s, 10_000))
     assert et.fusion_signature() is not None
     assert es.fusion_signature() is None     # SSM keeps its own scan
     # a different block-table width must not fuse (padding mismatch)
     cfg_t2 = replace(cfg_t, name="t2")
-    et2 = Engine(cfg_t2, pt, pool.register_model(cfg_t2, 10_000),
+    et2 = Engine(cfg_t2, with_model_axis(pt),
+                 pool.register_model(cfg_t2, 10_000),
                  max_blocks_per_seq=32)
     assert et2.fusion_signature() != et.fusion_signature()
 

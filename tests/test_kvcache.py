@@ -191,17 +191,19 @@ def test_quota_adaptation_moves_to_hot_model():
 
 
 def test_ssm_state_accounted():
+    """SSM state lives in the engine's slots: a pure-SSM view charges
+    neither quota nor arena, however long its sequences grow."""
     pool = _pool()
     m = configs.get_reduced("mamba2-2.7b")
     v = pool.register_model(m, quota=1024)
     assert v.group_size == 0                     # no attention blocks
-    assert v._ssm_blocks_per_seq > 0
     assert v.append_tokens(0, 100)
-    assert v.used == v._ssm_blocks_per_seq      # O(1) in tokens
+    assert v.used == 0 and pool.allocator.used == 0
     v.append_tokens(0, 400)
-    assert v.used == v._ssm_blocks_per_seq
+    assert v.used == 0 and 0 in v.seqs
+    assert v.can_append(1, 100_000)
     v.free_seq(0)
-    assert v.used == 0
+    assert v.used == 0 and not v.seqs
 
 
 @settings(max_examples=50, deadline=None)

@@ -14,6 +14,8 @@ from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
 
+from _weights import with_model_axis
+
 
 def test_end_to_end_pipeline_simulated():
     """Workload → Alg.1 placement → ADBS simulation: MuxServe's
@@ -51,7 +53,8 @@ def test_end_to_end_real_engines_multiplexed():
         cfg = cfgs[a]
         params[a] = init_params(jax.random.PRNGKey(i), cfg, jnp.float32)
         view = pool.register_model(cfg, 100_000)
-        engines[cfg.name] = Engine(cfg, params[a], view, max_slots=2)
+        engines[cfg.name] = Engine(cfg, with_model_axis(params[a]), view,
+                                   max_slots=2)
     mux = MuxScheduler(engines, pool, policy="adbs", adapt_every=4)
 
     rng = np.random.default_rng(0)
@@ -76,7 +79,7 @@ def test_end_to_end_real_engines_multiplexed():
     cfg = cfgs[archs[0]]
     pool2 = UnifiedKVPool(100_000, 64, dtype=jnp.float32)
     v2 = pool2.register_model(cfg, 100_000)
-    solo = Engine(cfg, params[archs[0]], v2, max_slots=1)
+    solo = Engine(cfg, with_model_axis(params[archs[0]]), v2, max_slots=1)
     q = Request(99, cfg.name, target.prompt, 3)
     solo.prefill([q])
     while not q.done:
@@ -93,7 +96,7 @@ def test_quota_pressure_backpressures_not_crashes():
     pool = UnifiedKVPool(group * 6, cfg.hd, dtype=jnp.float32)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
     view = pool.register_model(cfg, group * 6)
-    eng = Engine(cfg, params, view, max_slots=2)
+    eng = Engine(cfg, with_model_axis(params), view, max_slots=2)
     mux = MuxScheduler({cfg.name: eng}, pool, policy="adbs")
     rng = np.random.default_rng(1)
     for i in range(4):

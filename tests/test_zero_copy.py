@@ -19,6 +19,8 @@ from repro.serving.engine import (TRACE_COUNTS, Engine, Request, tree_bytes,
 from repro.serving.kvcache import UnifiedKVPool
 from repro.serving.mux import MuxScheduler
 
+from _weights import with_model_axis
+
 
 def _colocated(archs, fused, max_slots=2, quota=30_000, n_blocks=100_000,
                chunk_tokens=None):
@@ -30,7 +32,8 @@ def _colocated(archs, fused, max_slots=2, quota=30_000, n_blocks=100_000,
         cfg = replace(configs.get_reduced(a), name=f"m{i}")
         params = init_params(jax.random.PRNGKey(i), cfg, jnp.float32)
         view = pool.register_model(cfg, quota)
-        engines[cfg.name] = Engine(cfg, params, view, max_slots=max_slots,
+        engines[cfg.name] = Engine(cfg, with_model_axis(params), view,
+                                   max_slots=max_slots,
                                    chunk_tokens=chunk_tokens)
     return MuxScheduler(engines, pool, policy="adbs", fused=fused), pool
 
@@ -117,7 +120,8 @@ def test_serial_fallback_runs_off_stacked_tree():
     cfg1 = replace(configs.get_reduced("qwen2-7b"), name="m1")
     params = init_params(jax.random.PRNGKey(1), cfg1, jnp.float32)
     pool2 = UnifiedKVPool(50_000, 64, dtype=jnp.float32)
-    solo = Engine(cfg1, params, pool2.register_model(cfg1, 20_000),
+    solo = Engine(cfg1, with_model_axis(params),
+                  pool2.register_model(cfg1, 20_000),
                   max_slots=2)
     q = Request(9, "m1", list(prompt), 6)
     solo.prefill([q])
@@ -165,7 +169,7 @@ def test_fused_prefill_mixed_chunk_and_whole_prompt():
     for i, chunk in enumerate((8, 8, None)):
         cfg = replace(configs.get_reduced("qwen2-7b"), name=f"m{i}")
         params = init_params(jax.random.PRNGKey(i), cfg, jnp.float32)
-        engines[cfg.name] = Engine(cfg, params,
+        engines[cfg.name] = Engine(cfg, with_model_axis(params),
                                    pool.register_model(cfg, 30_000),
                                    max_slots=2, chunk_tokens=chunk)
     mux = MuxScheduler(engines, pool, policy="adbs", fused=True)
@@ -205,7 +209,8 @@ def test_bucketing_bounds_compile_count():
     cfg = replace(configs.get_reduced("qwen2-7b"), name="tc0")
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
     pool = UnifiedKVPool(100_000, 64, dtype=jnp.float32)
-    eng = Engine(cfg, params, pool.register_model(cfg, 50_000), max_slots=4)
+    eng = Engine(cfg, with_model_axis(params),
+                 pool.register_model(cfg, 50_000), max_slots=4)
 
     def wave(engine, lens, max_new, seed):
         rr = np.random.default_rng(seed)
@@ -227,7 +232,8 @@ def test_bucketing_bounds_compile_count():
     # nothing either
     cfg2 = replace(configs.get_reduced("qwen2-7b"), name="tc1")
     params2 = init_params(jax.random.PRNGKey(1), cfg2, jnp.float32)
-    eng2 = Engine(cfg2, params2, pool.register_model(cfg2, 30_000),
+    eng2 = Engine(cfg2, with_model_axis(params2),
+                  pool.register_model(cfg2, 30_000),
                   max_slots=4)
     wave(eng2, [11, 21, 41], max_new=5, seed=3)
     assert sum(TRACE_COUNTS.values()) == warm, \
