@@ -1,0 +1,10 @@
+"""device_idle_share (device): share of the traced window in which no
+operation ran on the device (1 − union of op intervals over the window),
+in %.  Device trace.  Moves output_tok_s."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
