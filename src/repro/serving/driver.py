@@ -51,6 +51,7 @@ from repro.core.workload import Workload
 from repro.serving.engine import Engine, Request, init_stacked_params
 from repro.serving.faults import FaultInjector, RecoveryCostModel
 from repro.serving.kvcache import UnifiedKVPool
+from repro.serving.metrics import SPANS, ServingMetrics, span
 from repro.serving.mux import MuxScheduler
 from repro.serving.reconfig import ReconfigController, WorkloadMonitor
 from repro.serving.sanitize import SessionSanitizer, sanitize_enabled
@@ -58,6 +59,10 @@ from repro.serving.sanitize import SessionSanitizer, sanitize_enabled
 # same default ladder as core/simulator.simulate — keep in sync, the
 # reports are meant to be compared side by side
 DEFAULT_SLO_SCALES: Tuple[float, ...] = (2.0, 4.0, 6.0, 8.0, 12.0, 16.0)
+
+# host spans of the serving loop (serving/metrics.py)
+_STEP, _SUBMIT = span("mux.step"), span("mux.submit")
+_TRACE = "mux.trace."           # event: a step program traced
 
 # ServeReport.to_json format version (DESIGN.md §14): bump on shape
 # changes so downstream tooling can diff runs across PRs
@@ -928,6 +933,8 @@ class ServeSession:
             u.clock = self.clock
             for eng in u.engines.values():
                 eng.clock = self.clock
+        # the serving path's spans are this session's, on its clock
+        SPANS.install(self.clock)
 
         # fault injection: one injector serves every unit and the
         # migration executor; recovery stalls are priced like any tick
@@ -983,6 +990,9 @@ class ServeSession:
         # observation sees each disposition exactly once
         self._fin_idx = [0] * len(self.units)
         self._shed_idx = [0] * len(self.units)
+        # each span name's totals in SPANS already exported (seconds,
+        # count), by name id
+        self._spans_seen: Tuple[List[float], List[int]] = ([], [])
         self._wall0 = time.perf_counter()  # muxlint: ok[clock] report bookkeeping: real elapsed wall seconds, never scheduling
 
         # runtime invariant sanitizer (serving/sanitize.py, DESIGN.md
@@ -1006,6 +1016,10 @@ class ServeSession:
         * ``("done", 0.0)`` — trace drained (or ``max_ticks`` hit);
           call ``report()``.
         """
+        with _STEP:
+            return self._step()
+
+    def _step(self) -> Tuple[str, float]:
         if self._done or (self.idx >= len(self.requests)
                           and not any(u.pending() for u in self.units)):
             if not self._done and self.sanitizer is not None:
@@ -1013,10 +1027,11 @@ class ServeSession:
             self._done = True
             return ("done", 0.0)
         now = self.clock()
-        while (self.idx < len(self.requests)
-               and self.requests[self.idx].arrival <= now):
-            self._submit(self.requests[self.idx])
-            self.idx += 1
+        with _SUBMIT:
+            while (self.idx < len(self.requests)
+                   and self.requests[self.idx].arrival <= now):
+                self._submit(self.requests[self.idx])
+                self.idx += 1
         busy = [u for u in self.units if u.pending()]
         status, wait = "tick", 0.0
         if busy:
@@ -1162,9 +1177,30 @@ class ServeSession:
         return ok
 
     # -- metrics observation (pure readers; never mutate serving state) --
+    def _observe_spans(self, m: ServingMetrics) -> None:
+        """Export what each span name added to SPANS's totals since the
+        last tick: seconds inside ``mux.*`` spans, and step programs
+        traced (``mux.trace.<step>`` events)."""
+        seconds, count, names = SPANS.seconds, SPANS.count, SPANS.names
+        seen_s, seen_c = self._spans_seen
+        grow = len(count) - len(seen_c)
+        seen_s.extend([0.0] * grow)
+        seen_c.extend([0] * grow)
+        for nid in range(len(count)):
+            k = count[nid] - seen_c[nid]
+            if k <= 0:
+                continue
+            name = names[nid]
+            if name.startswith(_TRACE):
+                m.step_traces.inc(k, step=name[len(_TRACE):])
+            else:
+                m.span_seconds.inc(seconds[nid] - seen_s[nid], span=name)
+            seen_s[nid], seen_c[nid] = seconds[nid], count[nid]
+
     def _observe_tick(self, busy: List[MuxScheduler]) -> None:
         m = self.metrics
         now = self.clock()
+        self._observe_spans(m)
         for u in busy:
             for name, t in u.tick_prefill_by.items():
                 m.tokens_total.inc(t, llm=name, phase="prefill")

@@ -51,6 +51,7 @@ from repro.models.layers import (attn_qkv, causal_attention, lm_logits,
 from repro.models.transformer import init_params
 from repro.serving import cache_ops
 from repro.serving.kvcache import ModelCacheView
+from repro.serving.metrics import event, span
 
 
 @dataclass
@@ -188,10 +189,28 @@ TRACE_COUNTS: Counter = Counter()
 
 def _note_trace(name: str) -> None:
     TRACE_COUNTS[name] += 1
+    event(f"mux.trace.{name}")
 
 
 def total_traces() -> int:
     return sum(TRACE_COUNTS.values())
+
+
+# the four host phases of a step, each a child span of the step's span:
+#   prep    host work before the jitted call (tables, padding, transfers
+#           to the device, the SSM state's gathers)
+#   launch  the jitted call until it returns (it dispatches, asynchronously)
+#   sync    the host blocked on the step's sampled tokens
+#   commit  ``apply_*_result``, with the SSM state's scatter and rollback
+STEP_PHASES = ("prep", "launch", "sync", "commit")
+
+
+def step_spans(kind: str, name: str):
+    """Span handles (serving/metrics.py) of one ``kind`` step
+    ("decode" | "prefill") of LLM or fused group ``name``: the step's
+    ``mux.<kind>.<name>`` and its phases, interned once per owner."""
+    root = f"mux.{kind}.{name}"
+    return (span(root),) + tuple(span(f"{root}.{p}") for p in STEP_PHASES)
 
 
 def _select_model(params, midx):
@@ -268,6 +287,10 @@ class Engine:
         Attention families only (SSM state chunking is a natural
         extension — the mixer already carries state)."""
         self.cfg = cfg
+        # host spans of this LLM's steps and admissions
+        self.decode_spans = step_spans("decode", cfg.name)
+        self.prefill_spans = step_spans("prefill", cfg.name)
+        self.admit_span = span(f"mux.admit.{cfg.name}")
         # request timestamps (first_token/finish) are stamped from this
         # clock so a deterministic driver can own the time domain
         # (serving/driver.py); MuxScheduler re-points it on all engines
@@ -469,15 +492,43 @@ class Engine:
         """
         if self.chunk_tokens:
             return self._prefill_chunked(reqs)
-        reqs = reqs[:len(self.free_slots())]
-        admitted = []
-        pending = 0
-        for r in reqs:
-            if self.can_admit(r, pending):
-                admitted.append(r)
-                pending += self.lifetime_blocks(r)
+        with self.admit_span:
+            reqs = reqs[:len(self.free_slots())]
+            admitted = []
+            pending = 0
+            for r in reqs:
+                if self.can_admit(r, pending):
+                    admitted.append(r)
+                    pending += self.lifetime_blocks(r)
         if not admitted:
             return 0
+        step, prep, launch, sync, commit = self.prefill_spans
+        with step:
+            with prep:
+                args, slot_ids, seq_ids = self._prefill_inputs(admitted)
+            with launch:
+                pool_k, pool_v, logits, new_ssm, new_tail = \
+                    self._prefill_fn(self.params, self.model_index, *args)
+            B = len(admitted)
+            self.pool.k, self.pool.v = pool_k, pool_v
+            if self.cfg.ssm:
+                with commit:
+                    sl = jnp.asarray(slot_ids)
+                    self.ssm_state = self.ssm_state.at[:, sl].set(
+                        new_ssm[:, :B])
+                    self.conv_tail = self.conv_tail.at[:, sl].set(
+                        new_tail[:, :B].astype(self.conv_tail.dtype))
+            # sample first token
+            with sync:
+                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
+            with commit:
+                return self._commit_prefill(admitted, slot_ids, seq_ids,
+                                            nxt)
+
+    def _prefill_inputs(self, admitted: List[Request]):
+        """Reserve the admitted prompts and bind their slots; returns
+        the whole-prompt step's device arguments after the weights,
+        the slots and the sequence ids."""
         B = len(admitted)
         # shape buckets (DESIGN.md §5): rows to the next power of two,
         # prompt length to the next BLOCK_TOKENS multiple — the padded
@@ -504,17 +555,13 @@ class Engine:
         toks, lens, table = _pad_rows(
             Bp, (toks, 0), (lens, 0),
             (self.view.block_table(seq_ids, self.max_blocks), -1))
-        pool_k, pool_v, logits, new_ssm, new_tail = self._prefill_fn(
-            self.params, self.model_index, jnp.asarray(toks),
-            jnp.asarray(lens), self.pool.k, self.pool.v, jnp.asarray(table))
-        self.pool.k, self.pool.v = pool_k, pool_v
-        if self.cfg.ssm:
-            sl = jnp.asarray(slot_ids)
-            self.ssm_state = self.ssm_state.at[:, sl].set(new_ssm[:, :B])
-            self.conv_tail = self.conv_tail.at[:, sl].set(
-                new_tail[:, :B].astype(self.conv_tail.dtype))
-        # sample first token
-        nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
+        return ((jnp.asarray(toks), jnp.asarray(lens), self.pool.k,
+                 self.pool.v, jnp.asarray(table)), slot_ids, seq_ids)
+
+    def _commit_prefill(self, admitted: List[Request], slot_ids, seq_ids,
+                        nxt: np.ndarray) -> int:
+        """Commit the whole-prompt step's first tokens; returns the
+        prompt tokens processed."""
         for i, r in enumerate(admitted):
             if r.max_new_tokens <= 0:
                 # degenerate prefill-only request: done at prompt end,
@@ -538,7 +585,7 @@ class Engine:
                     # tick would append a second token past max_new
                     # and bill a spurious decode step to the timeline
                     self._finish_slot(slot_ids[i], r)
-        return int(lens.sum())
+        return sum(len(r.prompt) for r in admitted)
 
     # ------------------------------------------------------------------
     def _adopt_prefix(self, sid: int, r: Request) -> int:
@@ -563,38 +610,39 @@ class Engine:
         bind a slot and mark it in-flight — no compute.  The chunk
         advance itself runs either serially (``run_chunk_job``) or as
         part of a fused group sweep (``FusedGroup.prefill``)."""
-        # admission: same cumulative lifetime check as the unchunked
-        # path; prompts reserve immediately, so only the not-yet-
-        # reserved growth of earlier admits carries into ``pending``
-        pending = 0
-        for r in reqs[:len(self.free_slots())]:
-            if not self.free_slots():
-                break
-            if not self.can_admit(r, pending):
-                continue
-            slot = self.free_slots()[0]
-            sid = self._next_seq
-            self._next_seq += 1
-            used_before = self.view.used
-            hit = self._adopt_prefix(sid, r)
-            ok = self.view.append_tokens(sid, len(r.prompt) - hit)
-            if not ok and hit:
-                # adoption landed but the private remainder could not
-                # be carved out — drop the shared refs and admit the
-                # request unshared (the lifetime check covered it)
-                self.view.free_seq(sid)
-                hit = 0
-                ok = self.view.append_tokens(sid, len(r.prompt))
-            assert ok
-            pending += self.lifetime_blocks(r) - (self.view.used
-                                                  - used_before)
-            self.slots[slot] = r
-            self.slot_seq[slot] = sid
-            r._seq_id = sid
-            # prefill resumes at the first uncached token — a partial
-            # hit leaves prefill_done/first_token stamping untouched
-            # (they stamp at prompt completion, whenever that is)
-            self._prefilling[slot] = hit
+        with self.admit_span:
+            # admission: same cumulative lifetime check as the unchunked
+            # path; prompts reserve immediately, so only the not-yet-
+            # reserved growth of earlier admits carries into ``pending``
+            pending = 0
+            for r in reqs[:len(self.free_slots())]:
+                if not self.free_slots():
+                    break
+                if not self.can_admit(r, pending):
+                    continue
+                slot = self.free_slots()[0]
+                sid = self._next_seq
+                self._next_seq += 1
+                used_before = self.view.used
+                hit = self._adopt_prefix(sid, r)
+                ok = self.view.append_tokens(sid, len(r.prompt) - hit)
+                if not ok and hit:
+                    # adoption landed but the private remainder could not
+                    # be carved out — drop the shared refs and admit the
+                    # request unshared (the lifetime check covered it)
+                    self.view.free_seq(sid)
+                    hit = 0
+                    ok = self.view.append_tokens(sid, len(r.prompt))
+                assert ok
+                pending += self.lifetime_blocks(r) - (self.view.used
+                                                      - used_before)
+                self.slots[slot] = r
+                self.slot_seq[slot] = sid
+                r._seq_id = sid
+                # prefill resumes at the first uncached token — a partial
+                # hit leaves prefill_done/first_token stamping untouched
+                # (they stamp at prompt completion, whenever that is)
+                self._prefilling[slot] = hit
 
     def export_prefill_job(self) -> Optional[PrefillJob]:
         """Snapshot the in-flight chunk rows the fused prefill sweep
@@ -655,21 +703,31 @@ class Engine:
                         self._finish_slot(sl, r)
         return done_tokens
 
-    def run_chunk_job(self, job: PrefillJob) -> int:
-        """Advance one exported chunk job serially (attention families):
-        one jitted step over a power-of-2 row bucket."""
-        B = len(job)
-        Bp = _next_pow2(B)
-        toks, offs, clens, table = _pad_rows(
-            Bp, (job.toks, 0), (job.offs, 0), (job.clens, 0),
-            (self.view.block_table(job.seq_ids, self.max_blocks), -1))
-        pool_k, pool_v, logits = self._chunk_fn(
-            self.params, self.model_index, jnp.asarray(toks),
-            jnp.asarray(offs), jnp.asarray(clens), self.pool.k, self.pool.v,
-            jnp.asarray(table))
-        self.pool.k, self.pool.v = pool_k, pool_v
-        nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
-        return self.apply_prefill_result(job, nxt)
+    def run_chunk_job(self, job: Optional[PrefillJob] = None) -> int:
+        """Advance one chunk job serially (attention families): one
+        jitted step over a power-of-2 row bucket.  ``job`` defaults to
+        the engine's in-flight chunks (``export_prefill_job``)."""
+        step, prep, launch, sync, commit = self.prefill_spans
+        with step:
+            with prep:
+                job = job or self.export_prefill_job()
+                B = len(job)
+                Bp = _next_pow2(B)
+                toks, offs, clens, table = _pad_rows(
+                    Bp, (job.toks, 0), (job.offs, 0), (job.clens, 0),
+                    (self.view.block_table(job.seq_ids, self.max_blocks),
+                     -1))
+                args = (jnp.asarray(toks), jnp.asarray(offs),
+                        jnp.asarray(clens), self.pool.k, self.pool.v,
+                        jnp.asarray(table))
+            with launch:
+                pool_k, pool_v, logits = self._chunk_fn(
+                    self.params, self.model_index, *args)
+            self.pool.k, self.pool.v = pool_k, pool_v
+            with sync:
+                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
+            with commit:
+                return self.apply_prefill_result(job, nxt)
 
     def _prefill_chunked(self, reqs: List[Request]) -> int:
         """Admit new requests, then advance every in-flight prefill by
@@ -679,26 +737,35 @@ class Engine:
             return 0
         if self.cfg.ssm:
             return self._run_chunk_ssm()
-        return self.run_chunk_job(self.export_prefill_job())
+        return self.run_chunk_job()
 
     def _run_chunk_ssm(self) -> int:
         """Chunk advance for pure-SSM engines (state carry, no pool)."""
-        job = self.export_prefill_job()
-        sl_idx = jnp.asarray(np.array(job.slots))
-        st = self.ssm_state[:, sl_idx]
-        tail = self.conv_tail[:, sl_idx]
-        # fresh sequences start from zero state
-        fresh = jnp.asarray((job.offs == 0).astype(np.float32))
-        st = st * (1.0 - fresh)[None, :, None, None, None]
-        tail = tail * (1.0 - fresh[None, :, None, None]).astype(tail.dtype)
-        logits, new_st, new_tail = self._chunk_fn(
-            self.params, self.model_index, jnp.asarray(job.toks),
-            jnp.asarray(job.clens), st, tail)
-        self.ssm_state = self.ssm_state.at[:, sl_idx].set(new_st)
-        self.conv_tail = self.conv_tail.at[:, sl_idx].set(
-            new_tail.astype(self.conv_tail.dtype))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        return self.apply_prefill_result(job, nxt)
+        step, prep, launch, sync, commit = self.prefill_spans
+        with step:
+            with prep:
+                job = self.export_prefill_job()
+                sl_idx = jnp.asarray(np.array(job.slots))
+                st = self.ssm_state[:, sl_idx]
+                tail = self.conv_tail[:, sl_idx]
+                # fresh sequences start from zero state
+                fresh = jnp.asarray((job.offs == 0).astype(np.float32))
+                st = st * (1.0 - fresh)[None, :, None, None, None]
+                tail = tail * (1.0 - fresh[None, :, None, None]).astype(
+                    tail.dtype)
+                args = (jnp.asarray(job.toks), jnp.asarray(job.clens), st,
+                        tail)
+            with launch:
+                logits, new_st, new_tail = self._chunk_fn(
+                    self.params, self.model_index, *args)
+            with commit:
+                self.ssm_state = self.ssm_state.at[:, sl_idx].set(new_st)
+                self.conv_tail = self.conv_tail.at[:, sl_idx].set(
+                    new_tail.astype(self.conv_tail.dtype))
+            with sync:
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with commit:
+                return self.apply_prefill_result(job, nxt)
 
     # ------------------------------------------------------------------
     def export_decode_job(self) -> Optional[DecodeJob]:
@@ -800,46 +867,57 @@ class Engine:
 
     def decode(self, job: Optional[DecodeJob] = None) -> int:
         """One decode step over all active slots.  Returns #tokens."""
-        job = job or self.export_decode_job()
-        if job is None:
-            return 0
-        B = len(job)
-        lens = self.view.seq_lens(job.seq_ids)  # incl. reserved current token
-        table = self.view.block_table(job.seq_ids, self.max_blocks)
-        last_tok = job.last_tok
-        if not self.cfg.ssm:
-            # power-of-2 row bucket (padded rows: len 1, table −1 —
-            # one masked garbage softmax, discarded below).  SSM/hybrid
-            # keep exact rows: their per-slot state scatter must not
-            # see duplicated padded slot indices.
-            Bp = _next_pow2(B)
-            if Bp != B:
-                last_tok, lens, table = _pad_rows(
-                    Bp, (job.last_tok, 0), (lens, 1), (table, -1))
-        sl = jnp.asarray(np.array(job.slots))
-
-        ssm_state = self.ssm_state[:, sl] if self.cfg.ssm else None
-        conv_tail = self.conv_tail[:, sl] if self.cfg.ssm else None
-        pool_k, pool_v, logits, new_ssm, new_tail = self._decode_fn(
-            self.params, self.model_index, jnp.asarray(last_tok),
-            jnp.asarray(lens), self.pool.k, self.pool.v, jnp.asarray(table),
-            ssm_state, conv_tail)
-        self.pool.k, self.pool.v = pool_k, pool_v
-        if self.cfg.ssm:
-            prev_ssm, prev_tail = self.ssm_state, self.conv_tail
-            self.ssm_state = self.ssm_state.at[:, sl].set(new_ssm)
-            self.conv_tail = self.conv_tail.at[:, sl].set(new_tail)
-        nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
-        toks = self.apply_decode_result(job, nxt)
-        if self.cfg.ssm and self._rolled_rows:
-            # rolled-back rows must retry from the PRE-step state: the
-            # SSM carry is not idempotent (re-advancing it on retry
-            # would silently change the eventually-committed token)
-            rs = jnp.asarray(np.array([job.slots[i]
-                                       for i in self._rolled_rows]))
-            self.ssm_state = self.ssm_state.at[:, rs].set(prev_ssm[:, rs])
-            self.conv_tail = self.conv_tail.at[:, rs].set(prev_tail[:, rs])
-        return toks
+        step, prep, launch, sync, commit = self.decode_spans
+        with step:
+            with prep:
+                job = job or self.export_decode_job()
+                if job is None:
+                    return 0
+                B = len(job)
+                # lengths include the reserved current token
+                lens = self.view.seq_lens(job.seq_ids)
+                table = self.view.block_table(job.seq_ids, self.max_blocks)
+                last_tok = job.last_tok
+                if not self.cfg.ssm:
+                    # power-of-2 row bucket (padded rows: len 1, table −1
+                    # — one masked garbage softmax, discarded below).
+                    # SSM/hybrid keep exact rows: their per-slot state
+                    # scatter must not see duplicated padded slot indices.
+                    Bp = _next_pow2(B)
+                    if Bp != B:
+                        last_tok, lens, table = _pad_rows(
+                            Bp, (job.last_tok, 0), (lens, 1), (table, -1))
+                sl = jnp.asarray(np.array(job.slots))
+                ssm_state = self.ssm_state[:, sl] if self.cfg.ssm else None
+                conv_tail = self.conv_tail[:, sl] if self.cfg.ssm else None
+                args = (jnp.asarray(last_tok), jnp.asarray(lens),
+                        self.pool.k, self.pool.v, jnp.asarray(table),
+                        ssm_state, conv_tail)
+            with launch:
+                pool_k, pool_v, logits, new_ssm, new_tail = self._decode_fn(
+                    self.params, self.model_index, *args)
+            self.pool.k, self.pool.v = pool_k, pool_v
+            if self.cfg.ssm:
+                with commit:
+                    prev_ssm, prev_tail = self.ssm_state, self.conv_tail
+                    self.ssm_state = self.ssm_state.at[:, sl].set(new_ssm)
+                    self.conv_tail = self.conv_tail.at[:, sl].set(new_tail)
+            with sync:
+                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
+            with commit:
+                toks = self.apply_decode_result(job, nxt)
+                if self.cfg.ssm and self._rolled_rows:
+                    # rolled-back rows must retry from the PRE-step state:
+                    # the SSM carry is not idempotent (re-advancing it on
+                    # retry would silently change the eventually-committed
+                    # token)
+                    rs = jnp.asarray(np.array([job.slots[i]
+                                               for i in self._rolled_rows]))
+                    self.ssm_state = self.ssm_state.at[:, rs].set(
+                        prev_ssm[:, rs])
+                    self.conv_tail = self.conv_tail.at[:, rs].set(
+                        prev_tail[:, rs])
+            return toks
 
     def has_decode_work(self) -> bool:
         return any(s not in self._prefilling for s in self.active_slots())

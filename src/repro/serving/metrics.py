@@ -15,6 +15,10 @@ provides that surface with zero third-party dependencies:
   engine/scheduler/driver/reconfig/faults, so call sites share one schema.
 - :class:`StructuredLog` — request-ID-correlated event records (bounded
   ring) for tracing a single request across submit/route/stream/finish.
+- :class:`SpanRecord` (one per process, ``SPANS``; ``span`` / ``event``)
+  — host spans of the serving path (a tick, an admission, each phase of
+  a decode or prefill step) on the serving clock, mirrored into the
+  profiler's trace; ``ServingMetrics`` sums them by name.
 - :class:`MetricsServer` — optional stdlib-only HTTP endpoint serving the
   text exposition at ``/metrics``, the JSON snapshot at ``/metrics.json``,
   and a server-sent-events stream of structured-log records at ``/events``.
@@ -22,16 +26,18 @@ provides that surface with zero third-party dependencies:
 Determinism note: metric *values* are derived from the serving clock and
 request outcomes, so under the deterministic tick-cost clock two runs of
 the same trace produce identical snapshots.  Only the HTTP server (a
-daemon thread) touches wall time, and it is opt-in.
+daemon thread) touches wall time, and it is opt-in; spans read whatever
+clock the serving session installs.
 """
 
 from __future__ import annotations
 
 import json
 import threading
+from array import array
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Deque, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
     "Counter",
@@ -40,6 +46,10 @@ __all__ = [
     "MetricsRegistry",
     "ServingMetrics",
     "StructuredLog",
+    "SpanRecord",
+    "SPANS",
+    "span",
+    "event",
     "MetricsServer",
     "DEFAULT_LATENCY_BUCKETS",
 ]
@@ -378,6 +388,197 @@ class StructuredLog:
         return "\n".join(json.dumps(r.to_dict(), sort_keys=True) for r in recs)
 
 
+def _no_clock() -> float:
+    return 0.0
+
+
+def _not_profiling() -> bool:
+    return False
+
+
+_NAN = float("nan")
+
+
+class _Span:
+    """Context manager of one interned span name.  It holds no state of
+    its own (the record keeps the stack of open spans), so one handle
+    per name serves every entry into it, nested ones included."""
+
+    __slots__ = ("record", "nid", "name")
+
+    def __init__(self, record: "SpanRecord", nid: int, name: str):
+        self.record = record
+        self.nid = nid
+        self.name = name
+
+    def __enter__(self) -> "_Span":
+        rec = self.record
+        ann = None
+        if rec._profiling():
+            ann = rec._annotation(self.name)
+            ann.__enter__()
+        rec._ann.append(ann)
+        i = rec.n
+        if i < rec.capacity:
+            rec.name[i] = self.nid
+            rec.parent[i] = rec._stack[-1]
+            rec.t1[i] = _NAN
+            rec.n = i + 1
+        else:
+            rec.overflowed = True
+            i = -1
+        rec._stack.append(i)
+        t0 = rec.clock()
+        if i >= 0:
+            rec.t0[i] = t0
+        rec._t0.append(t0)
+        return self
+
+    def __exit__(self, *exc: object) -> bool:
+        rec = self.record
+        t1 = rec.clock()
+        i = rec._stack.pop()
+        if i >= 0:
+            rec.t1[i] = t1
+        dt = t1 - rec._t0.pop()
+        if dt >= 0.0:                       # NaN: opened before a clear()
+            rec.seconds[self.nid] += dt
+            rec.count[self.nid] += 1
+        ann = rec._ann.pop()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        return False
+
+
+@dataclass
+class SpanView:
+    """A copy of the record: entry ``i`` is span ``names[name[i]]``,
+    inside entry ``parent[i]`` (−1: outermost), from ``t0[i]`` to
+    ``t1[i]`` (NaN while open; equal to ``t0`` for an event).  Entries
+    are in the order they opened, so a span's descendants follow it."""
+
+    names: List[str]
+    name: Any           # numpy arrays, one element per entry
+    parent: Any
+    t0: Any
+    t1: Any
+
+
+class SpanRecord:
+    """Bounded in-memory record of the serving path's host spans.
+
+    ``handle(name)`` interns a name once and returns its context
+    manager; entering it appends an entry (name id, parent entry, start
+    and end on ``clock``).  The entries live in preallocated arrays, so
+    recording allocates nothing the garbage collector walks.  While the
+    profiler runs, each span also opens a ``jax.profiler.TraceAnnotation``
+    of the same name (imported with the first handle), which puts it on
+    the device trace's timeline.  ``event(name)`` records an instant.
+    Besides the entries, ``seconds[nid]`` and ``count[nid]`` total each
+    name's closed spans (and events); they never overflow, and are what
+    ``ServingMetrics`` exports.
+
+    The record belongs to the current serving session:
+    ``ServeSession`` installs its clock here, which clears the record.
+    Once ``capacity`` entries are taken no more entries are recorded
+    (the totals go on) and ``overflowed`` is set; ``view()`` then
+    returns None rather than a partial record.  2^18 entries hold about
+    13,000 ticks of 20 spans.
+    """
+
+    def __init__(self, capacity: int = 1 << 18):
+        self.capacity = int(capacity)
+        self.names: List[str] = []
+        self._handles: Dict[str, _Span] = {}
+        self.name = array("i", bytes(4 * self.capacity))
+        self.parent = array("i", bytes(4 * self.capacity))
+        self.t0 = array("d", bytes(8 * self.capacity))
+        self.t1 = array("d", bytes(8 * self.capacity))
+        self.clock = _no_clock
+        # jax.profiler.TraceAnnotation and its "profiler on?" test, set
+        # by the first handle (no hard dependency on jax)
+        self._annotation = None
+        self._profiling = _not_profiling
+        # entries of the open spans, innermost last, over a −1 (none);
+        # their start times and their profiler annotations
+        self._stack: List[int] = [-1]
+        self._t0: List[float] = []
+        self._ann: List[object] = []
+        self.clear()
+
+    def install(self, clock) -> None:
+        """Time spans on ``clock`` from now on, and start empty."""
+        self.clock = clock
+        self.clear()
+
+    def clear(self) -> None:
+        """Drop every entry and total; spans open now close unrecorded."""
+        self.n = 0
+        self.overflowed = False
+        self._stack = [-1] * len(self._stack)
+        self._t0 = [_NAN] * len(self._t0)
+        self.seconds = array("d", bytes(8 * len(self.names)))
+        self.count = array("q", bytes(8 * len(self.names)))
+
+    def handle(self, name: str) -> _Span:
+        h = self._handles.get(name)
+        if h is None:
+            if self._annotation is None:
+                try:
+                    from jax.profiler import TraceAnnotation
+                    self._annotation = TraceAnnotation
+                    self._profiling = TraceAnnotation.is_enabled
+                except ImportError:
+                    self._annotation = False
+            h = self._handles[name] = _Span(self, len(self.names), name)
+            self.names.append(name)
+            self.seconds.append(0.0)
+            self.count.append(0)
+        return h
+
+    def event(self, name: str) -> None:
+        """Record an instant inside the innermost open span."""
+        nid = self.handle(name).nid
+        self.count[nid] += 1
+        i = self.n
+        if i < self.capacity:
+            self.name[i] = nid
+            self.parent[i] = self._stack[-1]
+            self.t0[i] = self.t1[i] = self.clock()
+            self.n = i + 1
+        else:
+            self.overflowed = True
+        if self._profiling():
+            with self._annotation(name):
+                pass
+
+    def view(self) -> Optional[SpanView]:
+        """A copy of every entry, or None once the record overflowed."""
+        if self.overflowed:
+            return None
+        import numpy as np
+        n = self.n
+        return SpanView(list(self.names),
+                        np.array(self.name[:n], np.int32),
+                        np.array(self.parent[:n], np.int32),
+                        np.array(self.t0[:n], np.float64),
+                        np.array(self.t1[:n], np.float64))
+
+
+# the serving path's record (one per process, as ``engine.TRACE_COUNTS``)
+SPANS = SpanRecord()
+
+
+def span(name: str) -> _Span:
+    """The context manager of span ``name`` in ``SPANS``; hot paths
+    keep the handle instead of calling this per step."""
+    return SPANS.handle(name)
+
+
+def event(name: str) -> None:
+    SPANS.event(name)
+
+
 class ServingMetrics:
     """The serving stack's concrete metric taxonomy.
 
@@ -468,6 +669,15 @@ class ServingMetrics:
             "mux_stream_errors_total", "Streams terminated with an error", ("reason",)
         )
 
+        # Host time by phase (the serving path's spans, ``SPANS``) and
+        # step programs traced while serving.
+        self.span_seconds = r.counter(
+            "mux_span_seconds_total", "Serving-clock seconds inside each span", ("span",)
+        )
+        self.step_traces = r.counter(
+            "mux_step_traces_total", "Step programs traced while serving", ("step",)
+        )
+
     def render(self) -> str:
         return self.registry.render()
 
@@ -552,22 +762,3 @@ class MetricsServer:
             self._httpd.shutdown()
         self._httpd.server_close()
         self._thread.join(timeout=2.0)
-
-
-def percentile_from_histogram(
-    hist: Histogram, q: float, **labels: str
-) -> Optional[float]:
-    """Estimate a quantile from cumulative buckets (upper-bound estimate).
-
-    Used by dashboards / the benchmark for coarse checks; exact latency
-    percentiles still come from the driver's LatencyStats.
-    """
-    with hist._lock:
-        s = hist._series.get(hist._key(labels))
-        if s is None or s.count == 0:
-            return None
-        rank = q * s.count
-        for ub, cum in zip(hist.buckets, s.buckets):
-            if cum >= rank:
-                return ub
-        return float("inf")

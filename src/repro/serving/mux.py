@@ -53,12 +53,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.engine import (Engine, Request, jitted_step, tree_bytes,
-                                  unique_tree_bytes)
+from repro.serving.engine import (Engine, Request, jitted_step, step_spans,
+                                  tree_bytes, unique_tree_bytes)
 from repro.serving.faults import FaultInjector
 from repro.serving.kvcache import UnifiedKVPool, fused_block_tables
+from repro.serving.metrics import span
 
 SHED_POLICIES = ("none", "reject", "deadline")
+
+# host spans of the scheduler (serving/metrics.py)
+_TICK, _QUOTA, _HARVEST = span("mux.tick"), span("mux.quota"), \
+    span("mux.harvest")
 
 
 @dataclass
@@ -139,6 +144,9 @@ class FusedGroup:
         self._decode_fn = jitted_step("fused_decode", self.cfg_key)
         self._prefill_fn = (jitted_step("fused_prefill_chunk", self.cfg_key)
                             if self.chunk_tokens else None)
+        name = "+".join(self.names)
+        self.decode_spans = step_spans("decode", name)
+        self.prefill_spans = step_spans("prefill", name)
 
     def weight_bytes(self) -> int:
         """Live weight bytes of the whole group (de-duplicated)."""
@@ -162,23 +170,29 @@ class FusedGroup:
         metering needs the split, not just the sum)."""
         pool = self.engines[0].pool
         rows = self.rows
-        toks = np.zeros((len(self.engines), rows), np.int32)
-        for m, job in enumerate(jobs):
-            if job is not None:
-                toks[m, :len(job)] = job.last_tok
-        tables, lens = fused_block_tables(
-            [(eng.view, job.seq_ids if job is not None else [])
-             for eng, job in zip(self.engines, jobs)],
-            rows, self.max_blocks)
-        pool.k, pool.v, logits = self._decode_fn(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            pool.k, pool.v, jnp.asarray(tables))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))        # [M, rows]
-        per: Dict[str, int] = {}
-        for m, (eng, job) in enumerate(zip(self.engines, jobs)):
-            if job is not None:
-                per[eng.cfg.name] = eng.apply_decode_result(
-                    job, nxt[m, :len(job)])
+        step, prep, launch, sync, commit = self.decode_spans
+        with step:
+            with prep:
+                toks = np.zeros((len(self.engines), rows), np.int32)
+                for m, job in enumerate(jobs):
+                    if job is not None:
+                        toks[m, :len(job)] = job.last_tok
+                tables, lens = fused_block_tables(
+                    [(eng.view, job.seq_ids if job is not None else [])
+                     for eng, job in zip(self.engines, jobs)],
+                    rows, self.max_blocks)
+                args = (jnp.asarray(toks), jnp.asarray(lens), pool.k,
+                        pool.v, jnp.asarray(tables))
+            with launch:
+                pool.k, pool.v, logits = self._decode_fn(self.params, *args)
+            with sync:
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))  # [M, rows]
+            with commit:
+                per: Dict[str, int] = {}
+                for m, (eng, job) in enumerate(zip(self.engines, jobs)):
+                    if job is not None:
+                        per[eng.cfg.name] = eng.apply_decode_result(
+                            job, nxt[m, :len(job)])
         return per
 
     def prefill(self, jobs) -> Dict[str, int]:
@@ -190,28 +204,35 @@ class FusedGroup:
         Returns #prompt tokens processed per member name."""
         pool = self.engines[0].pool
         rows, C, M = self.rows, self.chunk_tokens, len(self.engines)
-        toks = np.zeros((M, rows, C), np.int32)
-        offs = np.zeros((M, rows), np.int32)
-        clens = np.zeros((M, rows), np.int32)
-        tables = np.full((M, rows, self.max_blocks), -1, np.int32)
-        for m, (eng, job) in enumerate(zip(self.engines, jobs)):
-            if job is None:
-                continue
-            b = len(job)
-            toks[m, :b] = job.toks
-            offs[m, :b] = job.offs
-            clens[m, :b] = job.clens
-            tables[m, :b] = eng.view.block_table(job.seq_ids,
-                                                 self.max_blocks)
-        pool.k, pool.v, logits = self._prefill_fn(
-            self.params, jnp.asarray(toks), jnp.asarray(offs),
-            jnp.asarray(clens), pool.k, pool.v, jnp.asarray(tables))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))        # [M, rows]
-        per: Dict[str, int] = {}
-        for m, (eng, job) in enumerate(zip(self.engines, jobs)):
-            if job is not None:
-                per[eng.cfg.name] = eng.apply_prefill_result(
-                    job, nxt[m, :len(job)])
+        step, prep, launch, sync, commit = self.prefill_spans
+        with step:
+            with prep:
+                toks = np.zeros((M, rows, C), np.int32)
+                offs = np.zeros((M, rows), np.int32)
+                clens = np.zeros((M, rows), np.int32)
+                tables = np.full((M, rows, self.max_blocks), -1, np.int32)
+                for m, (eng, job) in enumerate(zip(self.engines, jobs)):
+                    if job is None:
+                        continue
+                    b = len(job)
+                    toks[m, :b] = job.toks
+                    offs[m, :b] = job.offs
+                    clens[m, :b] = job.clens
+                    tables[m, :b] = eng.view.block_table(job.seq_ids,
+                                                         self.max_blocks)
+                args = (jnp.asarray(toks), jnp.asarray(offs),
+                        jnp.asarray(clens), pool.k, pool.v,
+                        jnp.asarray(tables))
+            with launch:
+                pool.k, pool.v, logits = self._prefill_fn(self.params, *args)
+            with sync:
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))  # [M, rows]
+            with commit:
+                per: Dict[str, int] = {}
+                for m, (eng, job) in enumerate(zip(self.engines, jobs)):
+                    if job is not None:
+                        per[eng.cfg.name] = eng.apply_prefill_result(
+                            job, nxt[m, :len(job)])
         return per
 
 
@@ -687,20 +708,21 @@ class MuxScheduler:
             return []
         q = self.queues[name]
         eng = self.engines[name]
-        if q and eng.lifetime_blocks(q[0]) > eng.view.quota:
-            # adapt_quotas shrank this LLM's quota below the head
-            # request's whole lifetime — it would re-queue forever;
-            # pull spare quota back before trying to admit
-            self.pool.grant_min_quota(eng.view,
-                                      eng.lifetime_blocks(q[0]))
-        batch: List[Request] = []
-        pending = 0   # lifetime blocks of already-selected requests
-        while q and len(batch) < len(eng.free_slots()):
-            if eng.can_admit(q[0], pending):
-                pending += eng.lifetime_blocks(q[0])
-                batch.append(q.popleft())
-            else:
-                break
+        with eng.admit_span:
+            if q and eng.lifetime_blocks(q[0]) > eng.view.quota:
+                # adapt_quotas shrank this LLM's quota below the head
+                # request's whole lifetime — it would re-queue forever;
+                # pull spare quota back before trying to admit
+                self.pool.grant_min_quota(eng.view,
+                                          eng.lifetime_blocks(q[0]))
+            batch: List[Request] = []
+            pending = 0   # lifetime blocks of already-selected requests
+            while q and len(batch) < len(eng.free_slots()):
+                if eng.can_admit(q[0], pending):
+                    pending += eng.lifetime_blocks(q[0])
+                    batch.append(q.popleft())
+                else:
+                    break
         return batch
 
     def _run_prefill_round_robin(self) -> bool:
@@ -720,9 +742,12 @@ class MuxScheduler:
             eng = self.engines[name]
             batch = self._pull_batch(name)
             if batch or eng.has_prefill_work():
-                toks = eng.prefill(batch)
+                # admission time, before the step runs (as the fused and
+                # fcfs paths stamp it)
+                now = self.clock()
                 for r in batch:
-                    r.prefill_done = self.clock()
+                    r.prefill_done = now
+                toks = eng.prefill(batch)
                 self.stats.prefill_tokens += toks
                 self._meter(self.tick_prefill_by, name, toks)
                 self._prefill_rr = (self._prefill_rr + i + 1) % n
@@ -830,23 +855,25 @@ class MuxScheduler:
             else self._run_decode_round_robin()
 
     def _harvest(self) -> None:
-        for name, eng in self.engines.items():
-            if eng.finished:
-                self.stats.finished.extend(eng.finished)
-                eng.finished.clear()
-            if eng.preempted:
-                # stall-escape evictions go back to the head of their
-                # queue and restart from scratch on the next prefill —
-                # in (arrival, req_id) order, NOT eviction order: the
-                # engine preempts youngest-first, and letting that
-                # order leak into the retry queue would serve a later
-                # arrival before an earlier one evicted the same tick
-                # (and make the requeue order depend on slot layout)
-                for r in sorted(eng.preempted,
-                                key=lambda r: (r.arrival, r.req_id),
-                                reverse=True):
-                    self.queues[name].appendleft(r)
-                eng.preempted.clear()
+        with _HARVEST:
+            for name, eng in self.engines.items():
+                if eng.finished:
+                    self.stats.finished.extend(eng.finished)
+                    eng.finished.clear()
+                if eng.preempted:
+                    # stall-escape evictions go back to the head of
+                    # their queue and restart from scratch on the next
+                    # prefill — in (arrival, req_id) order, NOT eviction
+                    # order: the engine preempts youngest-first, and
+                    # letting that order leak into the retry queue would
+                    # serve a later arrival before an earlier one
+                    # evicted the same tick (and make the requeue order
+                    # depend on slot layout)
+                    for r in sorted(eng.preempted,
+                                    key=lambda r: (r.arrival, r.req_id),
+                                    reverse=True):
+                        self.queues[name].appendleft(r)
+                    eng.preempted.clear()
 
     # ------------------------------------------------------------------
     def tick(self) -> None:
@@ -875,6 +902,10 @@ class MuxScheduler:
         order the share-aware clock assumes when it computes the
         residual share from the tick's decode set (DESIGN.md §11).
         """
+        with _TICK:
+            self._tick()
+
+    def _tick(self) -> None:
         self.stats.ticks += 1
         self.tick_prefill_by = {}
         self.tick_decode_by = {}
@@ -902,7 +933,8 @@ class MuxScheduler:
             if self.stats.ticks % self.adapt_every == 0:
                 # Alg. 3's adapt_quota_periodically (sim counterpart:
                 # UnitSim._adapt_quotas, same low→high utilization move)
-                self.pool.adapt_quotas()
+                with _QUOTA:
+                    self.pool.adapt_quotas()
         elif self.policy == "round_robin":
             # no prefill priority, no quota adaptation
             if self.stats.ticks % 2 == 0:
@@ -937,18 +969,19 @@ class MuxScheduler:
                     and not busy_prefill and oldest_name not in self._down:
                 eng = self.engines[oldest_name]
                 q = self.queues[oldest_name]
-                if q and eng.lifetime_blocks(q[0]) > eng.view.quota:
-                    # same escape as _pull_batch: a head request whose
-                    # lifetime exceeds the LLM's quota would re-queue
-                    # forever (fcfs has no adaptation to fix it)
-                    self.pool.grant_min_quota(eng.view,
-                                              eng.lifetime_blocks(q[0]))
-                batch = []
-                pending = 0
-                while q and len(batch) < len(eng.free_slots()) \
-                        and eng.can_admit(q[0], pending):
-                    pending += eng.lifetime_blocks(q[0])
-                    batch.append(q.popleft())
+                with eng.admit_span:
+                    if q and eng.lifetime_blocks(q[0]) > eng.view.quota:
+                        # same escape as _pull_batch: a head request
+                        # whose lifetime exceeds the LLM's quota would
+                        # re-queue forever (fcfs has no adaptation)
+                        self.pool.grant_min_quota(
+                            eng.view, eng.lifetime_blocks(q[0]))
+                    batch = []
+                    pending = 0
+                    while q and len(batch) < len(eng.free_slots()) \
+                            and eng.can_admit(q[0], pending):
+                        pending += eng.lifetime_blocks(q[0])
+                        batch.append(q.popleft())
                 if batch:
                     now = self.clock()
                     for r in batch:

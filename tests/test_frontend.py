@@ -18,8 +18,7 @@ from repro.serving.engine import Request
 from repro.serving.faults import FaultPlan
 from repro.serving.frontend import (ServingFrontend, StreamCancelled,
                                     StreamShed, serve_and_collect)
-from repro.serving.metrics import (MetricsServer, ServingMetrics,
-                                   percentile_from_histogram)
+from repro.serving.metrics import MetricsServer, ServingMetrics
 from repro.serving.router import (ExplicitTarget, LeastLoaded, RoundRobin,
                                   Router, WeightedByRate, family_of,
                                   make_strategy)
@@ -286,8 +285,6 @@ def test_metrics_registry_and_exposition():
     assert 'mux_ttft_seconds_count{llm="a"} 3' in text
     assert 'mux_reconfig_events_total{kind="move"} 1' in text
     assert 'mux_fault_events_total{kind="engine_crash"} 1' in text
-    p50 = percentile_from_histogram(m.ttft_seconds, 0.5, llm="a")
-    assert p50 is not None and 0.004 <= p50 <= 0.4
     with pytest.raises(ValueError):
         m.requests_submitted.inc(-1, llm="a")
 
