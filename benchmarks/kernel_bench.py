@@ -21,6 +21,13 @@ from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models.mamba2 import ssd_chunked
 from repro.serving import cache_ops
+from repro.serving.kvcache import arena_block_shape
+
+
+def as_arena(blocks):
+    """[N, BT, hd] kernel blocks in the engine's arena form."""
+    n, bt, hd = blocks.shape
+    return blocks.reshape(n, *arena_block_shape(bt, hd))
 
 
 def _time(fn, *args, n=5) -> float:
@@ -58,8 +65,10 @@ def run(quick: bool = False) -> dict:
     qd = jax.random.normal(key, (4, h, hd), jnp.float32)
     table = jnp.arange(4 * nb, dtype=jnp.int32).reshape(4, nb) * group
     lens = jnp.full((4,), nb * bt, jnp.int32)
+    # the XLA path runs on the same blocks in the engine's arena form
     t_ref = _time(jax.jit(lambda *a: cache_ops.paged_decode_attention(
-        *a, 0, kv)), qd, pool_k, pool_v, table, lens, n=n_iters)
+        *a, 0, kv)), qd, as_arena(pool_k), as_arena(pool_v), table, lens,
+        n=n_iters)
     rows.append(("paged_decode_xla_ref", t_ref, f"b4 blocks{nb} bt{bt}"))
     t_pl = _time(lambda *a: paged_decode_attention(
         *a, 0, n_kv=kv, interpret=True), qd, pool_k, pool_v, table, lens,
@@ -97,6 +106,7 @@ def run(quick: bool = False) -> dict:
             jnp.concatenate(qs), pool_k, pool_v, phys,
             jnp.concatenate([lens] * M))
 
+    pool_fk, pool_fv = as_arena(pool_fk), as_arena(pool_fv)
     t_serial = _time(serial_sweep, pool_fk, pool_fv, n=n_iters)
     rows.append(("fused_decode_serial_dispatch", t_serial,
                  f"{M} models x b4 blocks{nb}"))

@@ -349,6 +349,7 @@ def kernel_phase(*, interpret=False, small=False, tol=KERNEL_TOL):
     from repro.paging import paged_decode_attention as paged_oracle
     from repro.paging import resolve_physical_blocks
     from repro.serving.cache_ops import fused_paged_chunk_attention
+    from repro.serving.kvcache import arena_block_shape
 
     bf = jnp.bfloat16
     keys = iter(jax.random.split(jax.random.PRNGKey(SEED), 64))
@@ -360,6 +361,11 @@ def kernel_phase(*, interpret=False, small=False, tol=KERNEL_TOL):
     BT, layer = 16, 3                    # BLOCK_TOKENS, a middle layer
     NB, N, B, S, C = (64, 8192, 4, 512, 128) if not small else \
         (4, 256, 2, 64, 16)
+
+    def as_arena(blocks):
+        # the kernels take [N, BT, hd] blocks; the engine's oracles are
+        # checked on the same bytes in the serving arena's form
+        return blocks.reshape(N, *arena_block_shape(BT, blocks.shape[-1]))
 
     def table(rows, n_kv, lens):
         # scattered group bases, −1 past each row's length
@@ -378,8 +384,8 @@ def kernel_phase(*, interpret=False, small=False, tol=KERNEL_TOL):
             f"paged_decode_attention hd{hd} H{H}/KV{KV} BT{BT}",
             lambda q, k, v, t, n, interpret, KV=KV: paged_decode_attention(
                 q, k, v, t, n, layer, n_kv=KV, interpret=interpret),
-            lambda q, k, v, t, n, KV=KV: paged_oracle(q, k, v, t, n, layer,
-                                                      KV),
+            lambda q, k, v, t, n, KV=KV: paged_oracle(
+                q, as_arena(k), as_arena(v), t, n, layer, KV),
             (normal((B, H, hd)), normal((N, BT, hd)), normal((N, BT, hd)),
              table(B, KV, lens), jnp.asarray(lens)), interpret, tol)
     # paged chunked prefill at phi-3's widths: a fresh prompt and a
@@ -391,7 +397,8 @@ def kernel_phase(*, interpret=False, small=False, tol=KERNEL_TOL):
         f"fused_paged_flash_prefill hd{hd} H{H} C{C}",
         lambda q, k, v, p, o, interpret: fused_paged_flash_prefill(
             q, k, v, p, o, interpret=interpret),
-        fused_paged_chunk_attention,
+        lambda q, k, v, p, o: fused_paged_chunk_attention(
+            q, as_arena(k), as_arena(v), p, o),
         (normal((2, C, H, hd)), normal((N, BT, hd)), normal((N, BT, hd)),
          phys, jnp.asarray(offs)), interpret, tol)
     # dense flash prefill at phi-3's widths with the default blocks

@@ -16,7 +16,9 @@ def flash_prefill_ref(q, k, v, *, window=None):
 
 
 def paged_decode_ref(q, pool_k, pool_v, table, seq_lens, layer, *, n_kv):
-    """Paged decode oracle — the engine's XLA path."""
+    """Paged decode oracle — the engine's XLA path.  Takes the kernels'
+    ``[N, BT, hd]`` blocks as they are: the engine's ops read a block's
+    size from the shape, so either arena form gives the same answer."""
     return _paged_ref(q, pool_k, pool_v, table, seq_lens, layer, n_kv)
 
 

@@ -6,6 +6,12 @@ and are used directly by the CPU engine and the dry-run lowering.
 Physical head-block id for (token-block base b, layer l, kv head h) of a
 model with KV kv-heads: ``b + l*KV + h`` (groups are contiguous —
 see serving/kvcache.py).
+
+The arena is ``[N, *block]``: each head-block is one lane-dense row
+``[BT*hd]``, or ``[BT, hd]`` where hd fills whole lane tiles (serving/
+kvcache.py ``arena_block_shape`` says why).  Every op here reads and
+writes whole blocks, so it works on either form, and reads ``BT`` as a
+block's size over hd.
 """
 from __future__ import annotations
 
@@ -14,9 +20,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.paging import (fused_paged_decode_attention,
-                          paged_decode_attention,
-                          resolve_physical_blocks)
+from repro.paging import (attend_blocks, fused_paged_decode_attention,
+                          paged_decode_attention, resolve_physical_blocks,
+                          take_blocks)
 
 __all__ = ["write_tokens", "resolve_physical_blocks", "copy_block_groups",
            "fused_paged_decode_attention", "paged_decode_attention",
@@ -38,7 +44,7 @@ def copy_block_groups(pool_k, pool_v, src_bases, dst_bases, n_kv, n_layers,
     destination arrays) and cross-pool KV migration (pass the source
     pool's arrays).
 
-    pool_k/pool_v: destination arena [N, BT, hd]
+    pool_k/pool_v: destination arena [N, *block]
     src_bases/dst_bases: group base per token-block (host lists)
     Returns updated (pool_k, pool_v).
     """
@@ -56,29 +62,45 @@ def copy_block_groups(pool_k, pool_v, src_bases, dst_bases, n_kv, n_layers,
 def write_tokens(pool_k, pool_v, k_new, v_new, table, start_pos, layer, n_kv):
     """Scatter new KV into the pool.
 
-    pool_k/v: [N, BT, hd]
+    pool_k/v: [N, *block]
     k_new/v_new: [B, S, KV, hd] — S new tokens starting at start_pos[b]
     table: [B, max_blocks] int32 group bases (−1 padded)
     start_pos: [B] int32 — position of the first new token
     Returns updated (pool_k, pool_v).
+
+    Writes whole blocks: every head-block the S tokens touch is
+    gathered, the new tokens are merged into it, and the blocks are
+    scattered back.  In the row form a token is a window at column
+    ``off*hd`` that is not lane-aligned, and the TPU compiler expands a
+    scatter of such windows into a loop over tokens; a scatter of
+    whole blocks stays one op.
     """
     B, S, KV, hd = k_new.shape
-    BT = pool_k.shape[1]
-    pos = start_pos[:, None] + jnp.arange(S)[None, :]          # [B,S]
-    blk = pos // BT                                            # [B,S]
-    off = pos % BT
-    base = jnp.take_along_axis(table, blk, axis=1)             # [B,S]
-    valid = base >= 0
-    phys = (jnp.maximum(base, 0)[:, :, None]
-            + layer * n_kv + jnp.arange(KV)[None, None, :])    # [B,S,KV]
-    off_b = jnp.broadcast_to(off[:, :, None], phys.shape)
-    # invalid slots → OOB index, dropped by scatter mode="drop"
-    phys = jnp.where(valid[:, :, None], phys, pool_k.shape[0])
-    pool_k = pool_k.at[phys.reshape(-1), off_b.reshape(-1)].set(
-        k_new.reshape(-1, hd), mode="drop")
-    pool_v = pool_v.at[phys.reshape(-1), off_b.reshape(-1)].set(
-        v_new.reshape(-1, hd), mode="drop")
-    return pool_k, pool_v
+    N, block = pool_k.shape[0], pool_k.shape[1:]
+    BT = math.prod(block) // hd
+    nb = (S + BT - 2) // BT + 1             # blocks S tokens can touch
+    blk = start_pos[:, None] // BT + jnp.arange(nb)              # [B,nb]
+    base = jnp.take_along_axis(table, blk, axis=1)               # [B,nb]
+    # invalid blocks → OOB index, dropped by the scatter
+    phys = jnp.where(base[..., None] >= 0,
+                     base[..., None] + (layer * n_kv + jnp.arange(KV)),
+                     N)                                          # [B,nb,KV]
+    # new token landing in slot t of block j (−1 / ≥ S: none)
+    i = blk[..., None] * BT + jnp.arange(BT) - start_pos[:, None, None]
+    fresh = ((i >= 0) & (i < S))[:, :, None, :, None]           # [B,nb,1,BT,1]
+    src = jnp.clip(i, 0, S - 1).reshape(B, nb * BT, 1, 1)
+    kv = jnp.take_along_axis(jnp.stack([k_new, v_new], axis=1),
+                             src[:, None], axis=2)      # [B,2,nb*BT,KV,hd]
+    kv = kv.reshape(B, 2, nb, BT, KV, hd).transpose(1, 0, 2, 4, 3, 5)
+    ids = phys.reshape(-1)
+
+    def put(pool, new):
+        old = take_blocks(pool, jnp.minimum(ids, N - 1))
+        blocks = jnp.where(fresh, new.astype(pool.dtype),
+                           old.reshape(B, nb, KV, BT, hd))
+        return pool.at[ids].set(blocks.reshape(-1, *block), mode="drop")
+
+    return put(pool_k, kv[0]), put(pool_v, kv[1])
 
 
 def fused_paged_chunk_attention(q, pool_k, pool_v, phys, q_offset):
@@ -92,30 +114,33 @@ def fused_paged_chunk_attention(q, pool_k, pool_v, phys, q_offset):
 
     q: [B, C, H, hd] (post-RoPE, absolute positions q_offset+i; rows
         may belong to different models)
-    pool_k/v: [N, BT, hd]
+    pool_k/v: [N, *block]
     phys: [B, n_kv, max_blocks] int32 physical head-block ids
     q_offset: [B] int32 absolute position of each row's first query
     Returns [B, C, H, hd].
     """
     B, C, H, hd = q.shape
-    BT = pool_k.shape[1]
+    BT = math.prod(pool_k.shape[1:]) // hd
     n_kv, max_blocks = phys.shape[1], phys.shape[2]
     group = H // n_kv
     scale = 1.0 / math.sqrt(hd)
 
-    k = pool_k[phys].reshape(B, n_kv, max_blocks * BT, hd)
-    v = pool_v[phys].reshape(B, n_kv, max_blocks * BT, hd)
-
     qh = q.reshape(B, C, n_kv, group, hd)
-    scores = jnp.einsum("bckgd,bktd->bkgct", qh, k).astype(jnp.float32) \
-        * scale
     t_pos = jnp.arange(max_blocks * BT)[None, None, None, None, :]
     q_pos = (q_offset[:, None] + jnp.arange(C))[:, None, None, :, None]
     mask = t_pos <= q_pos
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgct,bktd->bckgd", probs, v)
-    return out.reshape(B, C, H, hd)
+
+    def attend(k, v):                               # [B, n_kv, nb, *block]
+        k = k.reshape(B, n_kv, max_blocks * BT, hd)
+        v = v.reshape(B, n_kv, max_blocks * BT, hd)
+        scores = jnp.einsum("bckgd,bktd->bkgct", qh, k).astype(
+            jnp.float32) * scale
+        scores = jnp.where(mask, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bkgct,bktd->bckgd", probs, v)
+        return out.reshape(B, C, H, hd)
+
+    return attend_blocks(pool_k, pool_v, phys, attend)
 
 
 def paged_chunk_attention(q, pool_k, pool_v, table, q_offset, layer, n_kv):
@@ -126,7 +151,7 @@ def paged_chunk_attention(q, pool_k, pool_v, table, q_offset, layer, n_kv):
     serial and fused prefill sweeps share one set of semantics.
 
     q: [B, C, H, hd] (post-RoPE, absolute positions q_offset+i)
-    pool_k/v: [N, BT, hd]; table: [B, max_blocks]; q_offset: [B]
+    pool_k/v: [N, *block]; table: [B, max_blocks]; q_offset: [B]
     Returns [B, C, H, hd].
     """
     phys = resolve_physical_blocks(table, layer, n_kv)       # [B,KV,nb]

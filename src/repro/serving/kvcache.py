@@ -1,7 +1,8 @@
 """Unified head-wise KV cache pool (paper §3.4).
 
 The pool is a single arena of *head-blocks*: each block holds
-``BLOCK_TOKENS`` tokens of one KV head (``[BLOCK_TOKENS, head_dim]``).
+``BLOCK_TOKENS`` tokens of one KV head (``[BLOCK_TOKENS, head_dim]``;
+``arena_block_shape`` gives its form in the device arrays).
 Because the block shape is model-independent (head_dim is uniform across
 the colocated LLMs — 128 for LLaMA/GPT-3 per the paper; we check and
 group pools by head_dim), LLMs of different depths/head-counts share one
@@ -31,6 +32,30 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import BLOCK_TOKENS, ModelConfig
+from repro.paging import TILE_ROWS
+
+
+# lanes of a TPU vector register: the minor extent of every memory tile
+LANES = 128
+
+
+def arena_block_shape(block_tokens: int, head_dim: int) -> Tuple[int, ...]:
+    """Trailing shape of one head-block in the device arena.
+
+    A head_dim of whole 128-lane tiles keeps ``[block_tokens,
+    head_dim]``: the TPU lays that out row-major, each block whole
+    tiles, so the step programs gather whole tiles.  Below that, the
+    TPU's default layout of ``[N, block_tokens, head_dim]`` puts the
+    block axis N in the lanes, and each step program would copy the
+    whole arena to row-major and back around its scatters and gathers;
+    one lane-dense row of ``block_tokens*head_dim`` per block is
+    row-major with no padding.  (A row per block at head_dim 128 makes
+    each gathered block 16 partial tiles, and decode slower.)  Every
+    op on the arena reads ``block_tokens`` as the block's size over
+    ``head_dim``, so it runs on either form."""
+    if head_dim % LANES == 0:
+        return (block_tokens, head_dim)
+    return (block_tokens * head_dim,)
 
 
 class BlockAllocator:
@@ -57,17 +82,20 @@ class BlockAllocator:
         self.physical_used = 0
 
     def alloc(self, n: int) -> Optional[int]:
+        """First fit; a run of whole tile rows (``n`` a multiple of
+        ``TILE_ROWS``) starts on a tile row, which the row-form arena's
+        tile-row gathers rely on (``paging.attend_blocks``)."""
+        align = TILE_ROWS if n % TILE_ROWS == 0 else 1
         for i, (s, e) in enumerate(self._free):
-            if e - s >= n:
-                if e - s == n:
-                    self._free.pop(i)
-                else:
-                    self._free[i] = (s + n, e)
+            a = -(-s // align) * align
+            if e - a >= n:
+                self._free[i:i + 1] = [(lo, hi) for lo, hi in
+                                       ((s, a), (a + n, e)) if hi > lo]
                 self.used += n
                 self.physical_used += n
-                for b in range(s, s + n):
+                for b in range(a, a + n):
                     self._refs[b] = 1
-                return s
+                return a
         return None
 
     def share(self, start: int, n: int) -> None:
@@ -558,7 +586,12 @@ def fused_block_tables(views_seqs: List[Tuple["ModelCacheView", List[int]]],
 
 
 class UnifiedKVPool:
-    """The shared device arena + host allocator for one LLM unit."""
+    """The shared device arena + host allocator for one LLM unit.
+
+    ``k`` and ``v`` are ``[n_head_blocks, *block_shape]``: entry ``i``
+    is head-block ``i``, its tokens in order, ``head_dim`` values each
+    (``arena_block_shape``: one lane-dense row unless head_dim fills
+    whole lane tiles)."""
 
     def __init__(self, n_head_blocks: int, head_dim: int,
                  dtype=jnp.bfloat16, block_tokens: int = BLOCK_TOKENS,
@@ -570,8 +603,9 @@ class UnifiedKVPool:
         # pool-level so register_model (including the re-register on
         # crash recovery) creates per-view prefix indexes uniformly
         self.prefix_cache = prefix_cache
-        self.k = jnp.zeros((n_head_blocks, block_tokens, head_dim), dtype)
-        self.v = jnp.zeros((n_head_blocks, block_tokens, head_dim), dtype)
+        self.block_shape = arena_block_shape(block_tokens, head_dim)
+        self.k = jnp.zeros((n_head_blocks, *self.block_shape), dtype)
+        self.v = jnp.zeros((n_head_blocks, *self.block_shape), dtype)
         self.allocator = BlockAllocator(n_head_blocks)
         self.views: Dict[str, ModelCacheView] = {}
         self.used_by: Dict[str, int] = {}
@@ -604,13 +638,10 @@ class UnifiedKVPool:
             # no sequence holds blocks, so arena contents are dead —
             # reallocate at the final size instead of concatenating
             # (which would transiently hold 2× the arena)
-            self.k = jnp.zeros((n, self.block_tokens, self.head_dim),
-                               self.dtype)
-            self.v = jnp.zeros((n, self.block_tokens, self.head_dim),
-                               self.dtype)
+            self.k = jnp.zeros((n, *self.block_shape), self.dtype)
+            self.v = jnp.zeros((n, *self.block_shape), self.dtype)
         else:
-            pad = jnp.zeros((extra_blocks, self.block_tokens,
-                             self.head_dim), self.dtype)
+            pad = jnp.zeros((extra_blocks, *self.block_shape), self.dtype)
             self.k = jnp.concatenate([self.k, pad])
             self.v = jnp.concatenate([self.v, jnp.zeros_like(pad)])
         self.allocator.grow(extra_blocks)

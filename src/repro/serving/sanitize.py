@@ -41,6 +41,8 @@ import bisect
 import os
 from typing import Dict, List
 
+from repro.paging import TILE_ROWS
+
 __all__ = ["SanitizeError", "PoolSanitizer", "SchedulerSanitizer",
            "SessionSanitizer", "allocator_errors", "pool_errors",
            "sanitize_enabled"]
@@ -141,6 +143,12 @@ def pool_errors(pool) -> List[str]:
         if view.quota < 0:
             errs.append(f"view {name}: negative quota {view.quota}")
         for sid, sc in view.seqs.items():
+            off_tile = [b for b in sc.bases
+                        if view.group_size % TILE_ROWS == 0
+                        and b % TILE_ROWS]
+            if off_tile:
+                errs.append(f"view {name} seq {sid}: group bases "
+                            f"{off_tile[:4]} off a tile row")
             for base in sc.bases:
                 dead = [b for b in range(base, base + view.group_size)
                         if b not in refs]

@@ -86,11 +86,14 @@ def test_engine_pool_matches_pallas_kernel():
     lens = jnp.asarray(view.seq_lens(seq_ids))
     q = jax.random.normal(jax.random.PRNGKey(1),
                           (2, cfg.n_heads, cfg.hd), jnp.float32)
+    # the kernel takes the arena's rows as [N, BT, hd] blocks
+    blocks = (pool.n_head_blocks, pool.block_tokens, cfg.hd)
     for layer in (0, cfg.n_layers - 1):
         ref = cache_ops.paged_decode_attention(
             q, pool.k, pool.v, table, lens, layer, cfg.n_kv_heads)
-        pal = ops.paged_attention(q, pool.k, pool.v, table, lens, layer,
-                                  n_kv=cfg.n_kv_heads,
+        pal = ops.paged_attention(q, pool.k.reshape(blocks),
+                                  pool.v.reshape(blocks), table, lens,
+                                  layer, n_kv=cfg.n_kv_heads,
                                   backend="interpret")
         np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
                                    rtol=1e-4, atol=1e-4)

@@ -51,6 +51,21 @@ def test_allocator_exhaustion():
     assert a.alloc(8) == 0
 
 
+def test_allocator_starts_whole_tile_rows_on_a_tile_row():
+    """A run of whole 16-block tile rows starts on a tile row (the
+    row-form arena gathers such a model's heads by tile rows); other
+    runs stay first fit, and fill the gap the alignment left."""
+    a = BlockAllocator(128)
+    assert a.alloc(8) == 0
+    assert a.alloc(32) == 16
+    assert a.alloc(8) == 8
+    assert a.alloc(16) == 48
+    a.free(16, 32)
+    assert a.alloc(3) == 16
+    assert a.alloc(16) == 32
+    assert a.free_blocks == 128 - 8 - 8 - 16 - 3 - 16
+
+
 def test_allocator_shrink_exact_inverse_of_grow_when_idle():
     a = BlockAllocator(128)
     a.grow(64)

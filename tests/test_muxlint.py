@@ -307,6 +307,22 @@ def test_pool_sanitizer_clean_then_corrupted():
     view.used -= 1
 
 
+def test_pool_sanitizer_detects_a_group_off_its_tile_row():
+    from repro import configs
+    from repro.config import replace
+    from repro.serving.kvcache import BLOCK_TOKENS, UnifiedKVPool
+    pool = UnifiedKVPool(2_048, 64)
+    cfg = replace(configs.get_reduced("qwen2-7b"), n_heads=8, n_kv_heads=8,
+                  head_dim=64)
+    view = pool.register_model(cfg, quota=2_048)
+    assert view.group_size % 16 == 0
+    assert view.append_tokens(0, BLOCK_TOKENS)
+    PoolSanitizer(pool).check("aligned")
+    view.seqs[0].bases[0] += 1
+    with pytest.raises(SanitizeError, match="off a tile row"):
+        PoolSanitizer(pool).check("misaligned")
+
+
 def test_pool_sanitizer_detects_free_live_overlap():
     from repro import configs
     from repro.serving.kvcache import BLOCK_TOKENS, UnifiedKVPool

@@ -1,5 +1,6 @@
-"""Compile rehearsal: every Pallas kernel of the main path, compiled by
-the TPU compiler for a described (not attached) v5e chip at real widths.
+"""Compile rehearsal: every Pallas kernel of the main path, and the
+serving engine's step programs, compiled by the TPU compiler for a
+described (not attached) v5e chip at real widths.
 
 Interpret mode cannot see what only the chip's compiler refuses: tile
 alignment, fast-memory budgets, primitives Mosaic does not lower.  These
@@ -8,17 +9,22 @@ described inside a module fixture (never at import), so every test
 worker collects the same tests and only the worker given this file
 loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.config import BLOCK_TOKENS
+from repro.config import BLOCK_TOKENS, replace
+from repro.configs.phi_3_vision_4p2b import CONFIG as PHI3
 from repro.kernels.flash_prefill import (flash_prefill,
                                          fused_paged_flash_prefill)
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.paged_attention_int8 import paged_decode_attention_int8
 from repro.kernels.ssd_scan import ssd_scan
+from repro.serving.engine import init_stacked_params, jitted_step
+from repro.serving.kvcache import arena_block_shape
 
 ARENA, TABLE_W, ROWS = 8192, 64, 8
 
@@ -112,3 +118,52 @@ def test_ssd_scan_compiles_at_mamba2_widths(chip):
              ((1, s, h, p), jnp.bfloat16), ((1, s, h), jnp.float32),
              ((h,), jnp.float32), ((1, s, 1, n), jnp.bfloat16),
              ((1, s, 1, n), jnp.bfloat16), ((h,), jnp.float32))
+
+
+# the phi-3-vision-4.2b solo deployment's arena (8 slots of 784 tokens
+# over 32 layers x 32 KV heads); two of its 32 layers keep the compile
+# short, and the arena's copies do not depend on the layer count
+STEP_ARENA, STEP_ROWS, STEP_TABLE, STEP_CHUNK = 401_408, 8, 64, 256
+
+
+def _step_args(chip, kind, cfg):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_stacked_params(k, cfg, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    pool = spec((STEP_ARENA, *arena_block_shape(BLOCK_TOKENS, cfg.hd)),
+                jnp.bfloat16)
+    i32 = jnp.int32
+    if kind == "decode":
+        b = STEP_ROWS
+        return (params, spec((), i32), spec((b,), i32), spec((b,), i32),
+                pool, pool, spec((b, STEP_TABLE), i32), None, None)
+    b = 2
+    return (params, spec((), i32), spec((b, STEP_CHUNK), i32),
+            spec((b,), i32), spec((b,), i32), pool, pool,
+            spec((b, STEP_TABLE), i32))
+
+
+@pytest.mark.parametrize("hd", [64, 96, 128])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_step_writes_the_arena_in_place(chip, kind, hd):
+    """A step program updates the KV arena where it lies: the arena
+    keeps a row-major layout and is never copied (a [N, BT, hd] arena
+    with hd below 128 is laid out with N in the lanes, and a program
+    copies all of it four times), no per-token loop writes the KV, and
+    the temporaries are far below the arena's size."""
+    cfg = replace(PHI3, name="", n_layers=2, head_dim=hd)
+    compiled = jitted_step(kind, cfg).lower(
+        *_step_args(chip, kind, cfg)).compile()
+    text = compiled.as_text()
+    arena = re.compile(rf"= \w+\[{STEP_ARENA},[\d,]*\]"
+                       rf"\{{([\d,]*)\S* (copy|parameter)\(")
+    ops = [m.groups() for m in map(arena.search, text.splitlines()) if m]
+    block = arena_block_shape(BLOCK_TOKENS, hd)
+    row_major = ",".join(str(d) for d in range(len(block), -1, -1))
+    assert ops and all(op == "parameter" for _, op in ops), ops
+    assert {lay for lay, _ in ops} == {row_major}, ops
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
