@@ -1,9 +1,10 @@
 """Mamba-2 (mamba2-2.7b): the benchmark's own copy of the plain
 reference, its seeded weights and the work each serving step needs.
 
-Nothing here imports the system under test.  The weight tree has the
-layout the serving engine takes (leaves stacked on a leading model axis
-of 1, per-layer leaves on a layer axis).
+Only ``program_config`` imports the system under test (its
+configuration classes); the reference imports nothing of it.  The
+weight tree has the layout the serving engine takes (leaves stacked on
+a leading model axis of 1, per-layer leaves on a layer axis).
 
 Layer equations, as published (arXiv:2405.21060, ``Mamba2`` block with
 ``norm_before_gate=False``): in_proj → [z, xBC, dt]; depthwise causal
@@ -41,19 +42,17 @@ def dims(c: dict) -> dict:
 
 
 def program_config(c: dict) -> dict:
-    """Keyword arguments of the serving system's model configuration
-    (``ssm`` is filled in by the harness from ``ssm_config``)."""
-    m = dims(c)
+    """Keyword arguments of the serving system's model configuration,
+    its ``SSMConfig`` included."""
+    from repro.config import SSMConfig
+    m, s = dims(c), c["ssm_cfg"]
     return dict(family="ssm", n_layers=m["L"], d_model=m["d"], n_heads=0,
                 n_kv_heads=0, d_ff=0, vocab_size=m["V"],
-                rms_eps=m["eps"], tie_embeddings=True)
-
-
-def ssm_config(c: dict) -> dict:
-    s = c["ssm_cfg"]
-    return dict(d_state=s["d_state"], head_dim=s["headdim"],
-                expand=s["expand"], conv_kernel=s["d_conv"],
-                chunk_size=s["chunk_size"], n_groups=s["ngroups"])
+                rms_eps=m["eps"], tie_embeddings=True,
+                ssm=SSMConfig(d_state=s["d_state"], head_dim=s["headdim"],
+                              expand=s["expand"], conv_kernel=s["d_conv"],
+                              chunk_size=s["chunk_size"],
+                              n_groups=s["ngroups"]))
 
 
 def init_weights(key, c: dict, dtype):

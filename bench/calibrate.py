@@ -50,7 +50,7 @@ def main(argv=None) -> int:
                 control.setdefault(name, []).append(c["control"])
             print(f"[calibrate] seed {seed} {name}: served {c['value']} "
                   f"control {c.get('control')} over {c['tokens']} tokens "
-                  f"of {c['requests']} requests "
+                  f"of {c['requests']} requests in {c['slots']} slots "
                   f"({time.perf_counter() - t0:.1f} s)", flush=True)
         verdict = {"seed": seed, "correct": harness.passed(checks)}
         if args.control:

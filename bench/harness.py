@@ -1,10 +1,22 @@
 """One run of one cell: set-up, the measured window, the readers, the
 check.
 
-Everything that belongs to one configuration, traffic mix or metric is
-found by name: ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json`` (read by ``traffic/gen.py``) and
-``bench/metrics/<metric>.py``.  Nothing here branches on a cell.
+Everything that belongs to one configuration, traffic mix, cell or
+metric is found by name: ``bench/configs/<config>.json``,
+``bench/traffic/<traffic>.json`` (read by ``traffic/gen.py``),
+``bench/cells/<workload>.json`` and ``bench/metrics/<metric>.py``.
+Nothing here branches on a cell.
+
+A cell's own file, where it has one, holds what ``BENCHMARK.json``'s
+entry has no key for: ``check``, the ``sample_tokens`` and
+``sample_requests`` laid over the configuration's ``check`` for a cell
+that needs a larger sample than the configuration's other cells, and
+``about``, why.  Nothing else is read from it.
+
+A rehearsal (``rehearse=True``: reduced sizes, any backend) closes its
+window once every LLM has finished as many requests as it has slots,
+or after ``seconds`` at the latest, so that its check has requests of
+each LLM to compare however loaded the host is.
 """
 from __future__ import annotations
 
@@ -44,7 +56,11 @@ def load_cell(name: str) -> dict:
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise Refused(f"no workload {name!r} in BENCHMARK.json")
-    cell = cells[name]
+    cell = dict(cells[name])
+    own = os.path.join(BENCH, "cells", name + ".json")
+    check = _json(own).get("check") if os.path.isfile(own) else None
+    if check is not None:
+        cell["check"] = check
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     wanted = lambda m: "workloads" not in m or name in m["workloads"]  # noqa: E731
     return {"cell": cell,
@@ -171,6 +187,7 @@ class Recorder:
         self.clock = clock
         self.ticks: List[Tick] = []
         self.steps: List[Step] = []
+        self.slots: Dict[int, set] = {}     # id(request) -> slots decoded in
         tick = unit.tick
 
         def traced_tick():
@@ -195,6 +212,8 @@ class Recorder:
             if job is None:
                 return 0
             lens = eng.view.seq_lens(job.seq_ids).tolist()
+            for r, slot in zip(job.reqs, job.slots):
+                self.slots.setdefault(id(r), set()).add(slot)
             t0 = self.clock()
             with TraceAnnotation(f"bench.decode.{name}"):
                 n = decode(job)
@@ -235,6 +254,7 @@ class ReqRecord:
     output: List[int]
     finished: bool
     shed: bool
+    slots: Tuple[int, ...] = ()   # the engine slots it decoded in
 
 
 @dataclass
@@ -270,11 +290,14 @@ def reader(name: str):
 # the window
 # ---------------------------------------------------------------------------
 def serve(session, seconds: float, open_loop: bool, compiles: CompileCount,
-          trace_dir=None, drain_s: float = DRAIN_S):
-    """Drive ``session.step`` open loop on the wall clock.  Returns
-    (t_open, token stamps by request id, generator lateness, trace
-    window, drain end, traces and compiles inside the window), times on
-    the session's clock."""
+          trace_dir=None, drain_s: float = DRAIN_S,
+          until: Optional[Callable[[], bool]] = None):
+    """Drive ``session.step`` open loop on the wall clock.  ``until``
+    (rehearsals only) closes the window early, at the first step after
+    which it holds; a trace then covers the whole window.  Returns
+    (t_open, t_close, token stamps by request id, generator lateness,
+    trace window, drain end, traces and compiles inside the window),
+    times on the session's clock."""
     import jax
     from jax.profiler import TraceAnnotation
     clock = session.clock
@@ -314,9 +337,13 @@ def serve(session, seconds: float, open_loop: bool, compiles: CompileCount,
     t_close = t_open + seconds
     counted = [compiles.traces, compiles.compiles, None]
     t_trace = [t_close - min(TRACE_S, seconds / 2), t_close]
+    if until is not None:
+        t_trace[0] = t_open
     trace_win, ann = None, None
     while True:
         now = clock()
+        if until is not None and now < t_close and until():
+            t_close = t_trace[1] = now
         if trace_dir and ann is None and trace_win is None \
                 and now >= t_trace[0]:
             jax.profiler.start_trace(trace_dir)
@@ -333,7 +360,7 @@ def serve(session, seconds: float, open_loop: bool, compiles: CompileCount,
             counted[2] = {"traces": compiles.traces - counted[0],
                           "compiles": compiles.compiles - counted[1]}
         if now >= t_close:
-            waiting = [r for r in reqs if r.arrival < seconds
+            waiting = [r for r in reqs if r.arrival < t_close
                        and not stamps[id(r)] and not r.shed]
             if not open_loop or not waiting or now >= t_close + drain_s:
                 break
@@ -344,10 +371,10 @@ def serve(session, seconds: float, open_loop: bool, compiles: CompileCount,
                 nxt.append(clock() + wait)
             with TraceAnnotation("bench.wait"):
                 time.sleep(max(0.0, min(nxt) - clock()) if nxt else 0.001)
-    return t_open, stamps, late, trace_win, clock(), counted[2]
+    return t_open, t_close, stamps, late, trace_win, clock(), counted[2]
 
 
-def records(session, stamps, t_open) -> List[ReqRecord]:
+def records(session, stamps, t_open, slots) -> List[ReqRecord]:
     out = []
     for r in session.requests:
         out.append(ReqRecord(
@@ -356,7 +383,8 @@ def records(session, stamps, t_open) -> List[ReqRecord]:
             admitted=(r.prefill_done - t_open if r.prefill_done >= 0
                       else None),
             token_times=[t - t_open for t in stamps[id(r)]],
-            output=list(r.output), finished=r.finish >= 0, shed=r.shed))
+            output=list(r.output), finished=r.finish >= 0, shed=r.shed,
+            slots=tuple(sorted(slots.get(id(r), ())))))
     return out
 
 
@@ -410,8 +438,9 @@ class Bench:
                t_start: Optional[float] = None,
                fault: Optional[Callable] = None) -> Ctx:
         """Build the unit with ``seed``'s weights, warm it, serve
-        ``seed``'s traffic for ``seconds``, read the device's memory
-        peak, then free the unit.  ``fault`` (tests only) patches the
+        ``seed``'s traffic for ``seconds`` (a rehearsal: until every LLM
+        has finished as many requests as it has slots, ``seconds`` at
+        the latest), read the device's memory peak, then free the unit.  ``fault`` (tests only) patches the
         unit after it is built, to break the timed path."""
         import unit as unit_mod
         from repro.serving.driver import ServeSession, SLORef
@@ -445,21 +474,32 @@ class Bench:
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace \
             else None
         watch = GcWatch()
+        until = None
+        if self.rehearse:
+            slots = {n: e.max_slots for n, e in u.engines.items()}
+            until = lambda: all(  # noqa: E731
+                sum(r.finish >= 0 for r in preq if r.model == n) >= k
+                for n, k in slots.items())
         try:
             try:
-                t_open, stamps, late, twin, t_end, counts = serve(
+                t_open, t_close, stamps, late, twin, t_end, counts = serve(
                     session, seconds, open_loop, self.compiles, trace_dir,
-                    self.drain_s)
+                    self.drain_s, until)
             finally:
                 watch.close()
                 gc.unfreeze()
+            if until is not None:
+                early = t_close < t_open + seconds
+                seconds = t_close - t_open
+                say(f"[window] rehearsal closed at {seconds:.3f} s"
+                    + ("" if early else ", the latest allowed"))
             shift = lambda t: t - t_open  # noqa: E731
             ctx = Ctx(
                 seconds=seconds,
                 # set-up: from the process's start to the window's opening
                 setup_s=(session.clock.t0 + t_open - t_start
                          if t_start is not None else None),
-                requests=records(session, stamps, t_open),
+                requests=records(session, stamps, t_open, rec.slots),
                 ticks=[Tick(shift(t.t0), shift(t.t1), t.decode_by,
                             t.prefill_by, t.kv_used, t.kv_blocks)
                        for t in rec.ticks],
@@ -537,10 +577,11 @@ class Bench:
 
     def check(self, ctx: Ctx, seed: int, control: bool = False) -> dict:
         """Per LLM: the widest gap of the sampled served tokens below
-        the reference's best, its limit, and with ``control`` the fp8
-        control's reading on the same sample."""
+        the reference's best, its limit, the requests, tokens and engine
+        slots the sample covers, and with ``control`` the fp8 control's
+        reading on the same sample."""
         import check
-        ck = self.config["check"]
+        ck = {**self.config["check"], **self.cell.get("check", {})}
         out = {}
         for i, llm in enumerate(self.config["llms"]):
             picked = check.sample(ctx.requests, llm["name"], seed,
@@ -550,6 +591,7 @@ class Bench:
             out[f"gap.{llm['name']}"] = {
                 "value": g["served"], "limit": ck["gap_limit"][llm["name"]],
                 "tokens": g["tokens"], "requests": len(picked),
+                "slots": len({s for r in picked for s in r.slots}),
                 **({"control": g["control"]} if control else {})}
         return out
 

@@ -17,8 +17,10 @@ last lines of standard error are the numbers compared, each with its
 limit.  Without an accelerator, with too few chips, or on a chip
 ``bench/peaks.json`` does not know, it exits non-zero and prints no
 result.  ``--rehearse`` runs the cell at the configuration's reduced
-sizes on any backend (the CPU included) and reports no device metric:
-it is for rehearsals and tests, never for measurement.
+sizes on any backend (the CPU included), closes its window once every
+LLM has finished as many requests as it has slots (``--seconds`` at the
+latest), and reports no device metric: it is for rehearsals and tests,
+never for measurement.
 """
 from __future__ import annotations
 
@@ -62,7 +64,8 @@ def main(argv=None, fault=None) -> int:
         return 2
     for name, c in result["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['limit']}, "
-              f"{c['tokens']} tokens of {c['requests']} requests)",
+              f"{c['tokens']} tokens of {c['requests']} requests in "
+              f"{c['slots']} slots)",
               file=sys.stderr, flush=True)
     print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)

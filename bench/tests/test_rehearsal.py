@@ -9,10 +9,9 @@ import sys
 
 import pytest
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROOT = os.path.dirname(BENCH)
-CELLS = [w["name"] for w in json.load(
-    open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]]
+from _drive import BENCH, REHEARSAL_S, ROOT, cells
+
+CELLS = cells()
 
 
 def _run(args, cwd=ROOT):
@@ -45,7 +44,8 @@ def test_a_checkout_of_the_benchmark_alone_fails(tmp_path):
 def test_rehearsal_of_each_cell(cell, trace, capsys):
     import run
     assert run.main(["--workload", cell, "--seed", str(2 ** 31 + 17),
-                     "--seconds", "3", "--trace", str(trace),
+                     "--seconds", str(REHEARSAL_S),
+                     "--trace", str(trace),
                      "--rehearse"]) == 0
     out = capsys.readouterr()
     assert "compiles in window 0, traces 0" in out.out

@@ -60,11 +60,10 @@ def make_weights(llm: dict, seed: int, index: int, dtype: str):
 
 
 def program_model_config(llm: dict):
-    from repro.config import ModelConfig, SSMConfig
-    arch = arch_module(llm["arch"])
-    kw = arch.program_config(llm["config"])
-    if hasattr(arch, "ssm_config"):
-        kw["ssm"] = SSMConfig(**arch.ssm_config(llm["config"]))
+    """The serving system's ``ModelConfig`` of one LLM: its arch
+    module's keywords, with the name and source of its entry."""
+    from repro.config import ModelConfig
+    kw = arch_module(llm["arch"]).program_config(llm["config"])
     return ModelConfig(name=llm["name"], source=llm["source"], **kw)
 
 
