@@ -39,6 +39,16 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_coef: float = 0.01
+    d_shared: int = 0              # shared expert's width (0: none)
+    # the experts this chip holds of ``n_experts`` (expert parallelism):
+    # ``held_count`` contiguous experts from ``held_start``; 0 → all.
+    # The router still scores all ``n_experts``.
+    held_start: int = 0
+    held_count: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held_count or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -74,6 +84,17 @@ class ModelConfig:
     # ``attn_every`` SSM layers (0 → no attention at all, pure SSM).
     attn_every: int = 0
     shared_attn: bool = False      # Zamba2-style: one shared attn block
+    # hybrid layout as data (Granite-4.0-H): each layer's mixer,
+    # "mamba" or "attention", each followed by the MoE FFN.  Set, it
+    # replaces ``attn_every``/``shared_attn``.
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope: bool = True              # False: no positional encoding (NoPE)
+    # Granite multipliers: embeddings ×, each residual branch ×, logits
+    # ÷; the attention score scale (0 → 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
     # modality frontend stub: number of embedding-input channels.  When
     # not None the model accepts precomputed frame/patch embeddings of
     # shape [batch, n_prefix, frontend_dim] in addition to tokens.
@@ -92,6 +113,8 @@ class ModelConfig:
 
     @property
     def n_attn_layers(self) -> int:
+        if self.layer_types is not None:
+            return self.layer_types.count("attention")
         if self.family == "ssm":
             return 0
         if self.family == "hybrid":
@@ -102,9 +125,9 @@ class ModelConfig:
 
     @property
     def n_ssm_layers(self) -> int:
-        if self.family == "ssm":
-            return self.n_layers
-        if self.family == "hybrid":
+        if self.layer_types is not None:
+            return self.layer_types.count("mamba")
+        if self.family in ("ssm", "hybrid"):
             return self.n_layers
         return 0
 
@@ -130,7 +153,7 @@ class ModelConfig:
         per_mlp = 3 * d * f
         if self.moe:
             per_mlp = self.moe.n_experts * 3 * d * self.moe.d_expert \
-                + d * self.moe.n_experts
+                + d * self.moe.n_experts + 3 * d * self.moe.d_shared
         per_ssm = 0
         if self.ssm:
             di, N, H = self.d_inner, self.ssm.d_state, self.n_ssm_heads
@@ -140,7 +163,10 @@ class ModelConfig:
             out = di * d
             per_ssm = in_proj + conv + out + 3 * H + di  # A, D, dt_bias, gnorm
         total = n_emb + 2 * d  # final norm (w only; +d slack)
-        if self.family == "ssm":
+        if self.layer_types is not None:
+            total += self.n_ssm_layers * per_ssm \
+                + self.n_attn_layers * per_attn + L * (per_mlp + 2 * d)
+        elif self.family == "ssm":
             total += L * (per_ssm + d)
         elif self.family == "hybrid":
             total += L * (per_ssm + d)

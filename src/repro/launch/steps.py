@@ -111,8 +111,8 @@ def _attn_decode_token(x, lp, li, cfg, pos):
 def _ffn_decode(x, lp, li, cfg, dropless):
     h = rms_norm(x, lp["ln2"][li], cfg.rms_eps)
     if cfg.family == "moe":
-        fn = MoE.moe_ffn_dropless if dropless else MoE.moe_ffn
-        out, _ = fn(h[:, None, :], lp, li, cfg)
+        fn = MoE.moe_ffn_held if dropless else MoE.moe_ffn
+        out = fn(h[:, None, :], lp, li, cfg)[0]
         return x + out[:, 0]
     return x + mlp(h, lp, li)
 
@@ -227,8 +227,9 @@ def make_prefill_step(cfg: ModelConfig, window: Optional[int] = None,
                       moe_dropless: bool = False):
     """Returns prefill(params, tokens, lens[, prefix_emb]) → outputs dict.
 
-    ``moe_dropless``: per-token gathered experts (batch-composition-
-    independent outputs — the CPU engine/consistency-test path); default
+    ``moe_dropless``: the serving path's held-expert layer, no capacity
+    drop (batch-composition-independent outputs — the CPU
+    engine/consistency-test path); default
     is capacity-based dispatch (the distributed path).
     """
     fam = cfg.family
@@ -247,8 +248,8 @@ def make_prefill_step(cfg: ModelConfig, window: Optional[int] = None,
                                              window)
                 h = rms_norm(x, lp["ln2"][li], cfg.rms_eps)
                 if fam == "moe":
-                    fn = MoE.moe_ffn_dropless if moe_dropless else MoE.moe_ffn
-                    out, _ = fn(h, lp, li, cfg)
+                    fn = MoE.moe_ffn_held if moe_dropless else MoE.moe_ffn
+                    out = fn(h, lp, li, cfg)[0]
                     x = x + out
                 else:
                     x = x + mlp(h, lp, li)

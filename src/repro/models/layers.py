@@ -265,7 +265,9 @@ def init_attn(key, cfg: ModelConfig, n_layers: int, dtype=jnp.bfloat16) -> Param
 
 
 def attn_qkv(x, p, li, cfg: ModelConfig, positions):
-    """Project to q/k/v (+bias, qk_norm, rope).  x: [B,S,d]."""
+    """Project to q/k/v (+bias, qk_norm, rope unless the model has no
+    positional encoding, q scaled to ``attention_multiplier``).
+    x: [B,S,d]."""
     b, s, _ = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q = x @ p["wq"][li]
@@ -280,8 +282,13 @@ def attn_qkv(x, p, li, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"][li], cfg.rms_eps)
         k = rms_norm(k, p["k_norm"][li], cfg.rms_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.attention_multiplier:
+        # every attention path scales scores by 1/sqrt(hd): folding the
+        # rest of the model's own scale into q gives its scores
+        q = q * (cfg.attention_multiplier * math.sqrt(hd))
     return q, k, v
 
 

@@ -283,9 +283,17 @@ def mamba2_mixer(x, p, li, cfg: ModelConfig,
         # 16× compute waste on mamba2 prefill_32k — EXPERIMENTS.md §Perf)
         xh = constrain(xh, ("pod", "data"), None, "model", None)
         dt = constrain(dt, ("pod", "data"), None, "model")
+        # a sequence longer than a chunk but not a whole number of them
+        # is padded with steps of dt = 0, which leave the state as is;
+        # a whole number of chunks lowers with no pad at all
+        if s % chunk:
+            pad = ((0, 0), (0, -s % chunk)) + ((0, 0),) * 2
+            xh, Bg, Cg = (jnp.pad(a, pad) for a in (xh, Bg, Cg))
+            dt = jnp.pad(dt, pad[:3])
         y, final_state = ssd_chunked(xh, dt, p["a_log"][li], Bg, Cg,
                                      p["d_skip"][li], chunk,
                                      init_state=ssm_state)
+        y = y[:, :s]
     y = y.reshape(b, s, di)
     y = rms_norm(y * jax.nn.silu(z), p["gnorm"][li], cfg.rms_eps)
     out = y @ p["out_proj"][li]

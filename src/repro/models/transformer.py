@@ -8,6 +8,8 @@ control for the 512-device dry-run).  Families:
   moe                 : [attn → moe_ffn] × L
   ssm                 : [mamba2] × L
   hybrid (zamba2)     : [mamba2 (+ shared attn every k)] × L
+  hybrid (pattern)    : [mamba2 | attn → held moe_ffn] per layer_types
+                        (models/granite.py)
 
 VLM/audio frontends are stubs: precomputed patch/frame embeddings are
 consumed as prefix tokens (DESIGN.md §4).
@@ -20,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig, pad_vocab
+from repro.models import granite as G
 from repro.models import layers as Lyr
 from repro.models import mamba2 as M2
 from repro.models import moe as MoE
@@ -35,6 +38,8 @@ Params = Dict
 # init
 # ---------------------------------------------------------------------------
 def init_params(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
+    if cfg.layer_types is not None:
+        return G.init_params(key, cfg, dtype)
     keys = jax.random.split(key, 8)
     v_pad = pad_vocab(cfg.vocab_size)
     p: Params = {"tok": init_embed(keys[0], cfg, v_pad, dtype)}
@@ -95,8 +100,8 @@ def _mlp_block(x, lp, li, cfg: ModelConfig):
 
 def _moe_block(x, lp, li, cfg: ModelConfig, dropless: bool = False):
     h = rms_norm(x, lp["ln2"][li], cfg.rms_eps)
-    fn = MoE.moe_ffn_dropless if dropless else MoE.moe_ffn
-    out, aux = fn(h, lp, li, cfg)
+    fn = MoE.moe_ffn_held if dropless else MoE.moe_ffn
+    out, aux = fn(h, lp, li, cfg)[:2]
     return x + out, aux
 
 
@@ -117,7 +122,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     """Full causal forward.  Returns (logits [B,S_total,vocab], aux_loss).
 
     ``window`` optionally restricts attention (sliding-window variant).
+    ``moe_dropless``: the serving path's held-expert layer (no
+    capacity drop) in place of the capacity dispatch.  A layer-pattern
+    model runs its own layers (``models/granite.py``), unrolled, and
+    always returns the sliced vocabulary.
     """
+    if cfg.layer_types is not None:
+        assert prefix_emb is None and window is None
+        return G.forward(params, cfg, tokens), jnp.float32(0)
     x = embed_inputs(params, cfg, tokens, prefix_emb)
     x = shard_activation(x)
     b, s, _ = x.shape

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import BLOCK_TOKENS, ModelConfig, replace
+from repro.models import granite as G
 from repro.models import mamba2 as M2
 from repro.models import moe as MoE
 from repro.models.layers import (attn_qkv, causal_attention, lm_logits,
@@ -284,13 +285,17 @@ class Engine:
         time, one chunk per scheduler tick, so colocated LLMs' decode
         jobs interleave between chunks and a long prompt cannot
         monopolize the unit (bounds TTFT interference under ADBS).
-        Attention families only (SSM state chunking is a natural
-        extension — the mixer already carries state)."""
+        Attention models chunk against the pool, pure-SSM models carry
+        the mixer's state across chunks, and layer-pattern models
+        (``ModelConfig.layer_types``) do both; a zamba2-style hybrid
+        (shared attention every ``attn_every`` layers) keeps
+        whole-prompt prefill."""
         self.cfg = cfg
         # host spans of this LLM's steps and admissions
         self.decode_spans = step_spans("decode", cfg.name)
         self.prefill_spans = step_spans("prefill", cfg.name)
         self.admit_span = span(f"mux.admit.{cfg.name}")
+        self.moe_event = f"mux.moe.{cfg.name}"
         # request timestamps (first_token/finish) are stamped from this
         # clock so a deterministic driver can own the time domain
         # (serving/driver.py); MuxScheduler re-points it on all engines
@@ -302,11 +307,8 @@ class Engine:
         self.pool = view.pool
         self.max_slots = max_slots
         self.max_blocks = max_blocks_per_seq
-        # chunked prefill: attention families chunk against the pool;
-        # pure-SSM models chunk via the mixer's state carry.  Hybrid
-        # (zamba2) keeps whole-prompt prefill (mixed cache chunking is
-        # a straightforward extension, not done here).
-        self.chunk_tokens = None if cfg.family == "hybrid" else chunk_tokens
+        self.chunk_tokens = (None if cfg.family == "hybrid"
+                             and cfg.layer_types is None else chunk_tokens)
         self.slots: List[Optional[Request]] = [None] * max_slots
         self.slot_seq: np.ndarray = np.full(max_slots, -1, np.int64)
         self.finished: List[Request] = []
@@ -326,15 +328,15 @@ class Engine:
         # rebuilt by crash recovery); None = no streaming consumer.
         self.emit: Optional[Callable[[str, Request, int], None]] = None
 
-        # SSM per-slot state
+        # SSM per-slot state, one entry per SSM layer
         if cfg.ssm:
             sc = cfg.ssm
             conv_dim = cfg.d_inner + 2 * sc.n_groups * sc.d_state
             self.ssm_state = jnp.zeros(
-                (cfg.n_layers, max_slots, cfg.n_ssm_heads, sc.head_dim,
+                (cfg.n_ssm_layers, max_slots, cfg.n_ssm_heads, sc.head_dim,
                  sc.d_state), jnp.float32)
             self.conv_tail = jnp.zeros(
-                (cfg.n_layers, max_slots, sc.conv_kernel - 1, conv_dim),
+                (cfg.n_ssm_layers, max_slots, sc.conv_kernel - 1, conv_dim),
                 jnp.bfloat16 if params["tok"]["embed"].dtype == jnp.bfloat16
                 else params["tok"]["embed"].dtype)
         else:
@@ -740,30 +742,50 @@ class Engine:
         return self.run_chunk_job()
 
     def _run_chunk_ssm(self) -> int:
-        """Chunk advance for pure-SSM engines (state carry, no pool)."""
+        """Chunk advance for engines with SSM state: the state and conv
+        tail of the job's slots are gathered, carried through the chunk
+        and scattered back; a layer-pattern model's step also writes
+        and attends its paged KV.  Rows go to a power-of-2 bucket;
+        padded rows name slot ``max_slots``, past the state arrays, so
+        the gather clamps them and the scatter drops them."""
         step, prep, launch, sync, commit = self.prefill_spans
+        paged = self.cfg.layer_types is not None
         with step:
             with prep:
                 job = self.export_prefill_job()
-                sl_idx = jnp.asarray(np.array(job.slots))
+                B = len(job)
+                slots, toks, offs, clens = _pad_rows(
+                    _next_pow2(B), (np.array(job.slots), self.max_slots),
+                    (job.toks, 0), (job.offs, 0), (job.clens, 0))
+                sl_idx = jnp.asarray(slots)
                 st = self.ssm_state[:, sl_idx]
                 tail = self.conv_tail[:, sl_idx]
                 # fresh sequences start from zero state
-                fresh = jnp.asarray((job.offs == 0).astype(np.float32))
+                fresh = jnp.asarray((offs == 0).astype(np.float32))
                 st = st * (1.0 - fresh)[None, :, None, None, None]
                 tail = tail * (1.0 - fresh[None, :, None, None]).astype(
                     tail.dtype)
-                args = (jnp.asarray(job.toks), jnp.asarray(job.clens), st,
-                        tail)
+                if paged:
+                    table, = _pad_rows(len(slots), (self.view.block_table(
+                        job.seq_ids, self.max_blocks), -1))
+                    args = (jnp.asarray(toks), jnp.asarray(offs),
+                            jnp.asarray(clens), self.pool.k, self.pool.v,
+                            jnp.asarray(table), st, tail)
+                else:
+                    args = (jnp.asarray(toks), jnp.asarray(clens), st,
+                            tail)
             with launch:
-                logits, new_st, new_tail = self._chunk_fn(
-                    self.params, self.model_index, *args)
+                out = self._chunk_fn(self.params, self.model_index, *args)
+            if paged:
+                self.pool.k, self.pool.v, logits, new_st, new_tail = out
+            else:
+                logits, new_st, new_tail = out
             with commit:
                 self.ssm_state = self.ssm_state.at[:, sl_idx].set(new_st)
                 self.conv_tail = self.conv_tail.at[:, sl_idx].set(
                     new_tail.astype(self.conv_tail.dtype))
             with sync:
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
             with commit:
                 return self.apply_prefill_result(job, nxt)
 
@@ -878,16 +900,17 @@ class Engine:
                 lens = self.view.seq_lens(job.seq_ids)
                 table = self.view.block_table(job.seq_ids, self.max_blocks)
                 last_tok = job.last_tok
-                if not self.cfg.ssm:
-                    # power-of-2 row bucket (padded rows: len 1, table −1
-                    # — one masked garbage softmax, discarded below).
-                    # SSM/hybrid keep exact rows: their per-slot state
-                    # scatter must not see duplicated padded slot indices.
-                    Bp = _next_pow2(B)
-                    if Bp != B:
-                        last_tok, lens, table = _pad_rows(
-                            Bp, (job.last_tok, 0), (lens, 1), (table, -1))
-                sl = jnp.asarray(np.array(job.slots))
+                slots = np.array(job.slots)
+                # power-of-2 row bucket (padded rows: len 1, table −1 —
+                # one masked garbage softmax, discarded below — and slot
+                # ``max_slots``, past the SSM state: its gather clamps,
+                # its scatter drops)
+                Bp = _next_pow2(B)
+                if Bp != B:
+                    last_tok, lens, table, slots = _pad_rows(
+                        Bp, (job.last_tok, 0), (lens, 1), (table, -1),
+                        (slots, self.max_slots))
+                sl = jnp.asarray(slots)
                 ssm_state = self.ssm_state[:, sl] if self.cfg.ssm else None
                 conv_tail = self.conv_tail[:, sl] if self.cfg.ssm else None
                 args = (jnp.asarray(last_tok), jnp.asarray(lens),
@@ -903,7 +926,16 @@ class Engine:
                     self.ssm_state = self.ssm_state.at[:, sl].set(new_ssm)
                     self.conv_tail = self.conv_tail.at[:, sl].set(new_tail)
             with sync:
-                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
+                if self.cfg.moe:
+                    # with the tokens, in one transfer: the tokens routed
+                    # to each held expert of each layer, kept beside the
+                    # step's spans
+                    logits, load = logits
+                    nxt, load = jax.device_get(
+                        (jnp.argmax(logits[:B], axis=-1), load))
+                    event(self.moe_event, load)
+                else:
+                    nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
             with commit:
                 toks = self.apply_decode_result(job, nxt)
                 if self.cfg.ssm and self._rolled_rows:
@@ -961,14 +993,19 @@ class Engine:
 # singleton case run off the same buffers with zero weight copies.
 # ---------------------------------------------------------------------------
 def _prefill_chunk_impl(params, midx, toks, offs, clens, pool_k, pool_v,
-                        table, *, cfg: ModelConfig):
+                        table, ssm_state=None, conv_tail=None, *,
+                        cfg: ModelConfig):
     """One chunked-prefill step: process C prompt tokens per sequence at
     absolute positions offs+i, writing KV into the pool and attending
     against everything written so far.  Garbage KV at padded positions
     (i ≥ clens) lands on future decode slots, which decode overwrites
-    before attending — harmless by construction."""
+    before attending — harmless by construction.  A layer-pattern model
+    also carries its SSM state (``_pattern_chunk``)."""
     _note_trace("prefill_chunk")
     p = _select_model(params, midx)
+    if cfg.layer_types is not None:
+        return _pattern_chunk(p, toks, offs, clens, pool_k, pool_v, table,
+                              ssm_state, conv_tail, cfg)
     B, C = toks.shape
     x = p["tok"]["embed"][toks]
     positions = offs[:, None] + jnp.arange(C)[None, :]
@@ -986,8 +1023,8 @@ def _prefill_chunk_impl(params, midx, toks, offs, clens, pool_k, pool_v,
         attn_li += 1
         h = rms_norm(x, lp["ln2"][li], cfg.rms_eps)
         if cfg.family == "moe":
-            out, _ = MoE.moe_ffn_dropless(h, lp, li, cfg)
-            x = x + out
+            keep = jnp.arange(C)[None, :] < clens[:, None]
+            x = x + MoE.moe_ffn_held(h, lp, li, cfg, keep)[0]
         else:
             x = x + mlp(h, lp, li)
 
@@ -995,6 +1032,77 @@ def _prefill_chunk_impl(params, midx, toks, offs, clens, pool_k, pool_v,
     x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     logits = lm_logits(x_last, p["tok"], cfg)[..., :cfg.vocab_size]
     return pool_k, pool_v, logits
+
+
+def _pattern_chunk(p, toks, offs, clens, pool_k, pool_v, table, ssm_state,
+                   conv_tail, cfg: ModelConfig):
+    """Chunk step of a layer-pattern model (``models/granite.py``): each
+    layer's mixer by the pattern — Mamba-2 carries the SSM state and
+    conv tail at its SSM-layer ordinal through the chunk (``clens``
+    freezes them past a row's true length), attention writes the
+    chunk's KV at its attention-layer ordinal and attends everything
+    written so far — then the MoE FFN, padded positions routed nowhere.
+    Returns (pool_k, pool_v, logits, new_ssm, new_tail)."""
+    B, C = toks.shape
+    x = G.embed(p, cfg, toks)
+    positions = offs[:, None] + jnp.arange(C)[None, :]
+    keep = jnp.arange(C)[None, :] < clens[:, None]
+    new_ssm, new_tail = ssm_state, conv_tail
+    for li, (kind, oi) in enumerate(zip(cfg.layer_types,
+                                        G.mixer_ordinals(cfg))):
+        h = G.pre(x, p, li, cfg)
+        if kind == "mamba":
+            mixed, st, tail = M2.mamba2_mixer(
+                h, p["mamba"], oi, cfg, conv_tail=new_tail[oi],
+                ssm_state=new_ssm[oi], return_cache=True, length_mask=keep)
+            new_ssm = new_ssm.at[oi].set(st)
+            new_tail = new_tail.at[oi].set(tail.astype(new_tail.dtype))
+        else:
+            pa = p["attn"]
+            q, k, v = attn_qkv(h, pa, oi, cfg, positions)
+            pool_k, pool_v = cache_ops.write_tokens(
+                pool_k, pool_v, k, v, table, offs, oi, cfg.n_kv_heads)
+            o = cache_ops.paged_chunk_attention(
+                q, pool_k, pool_v, table, offs, oi, cfg.n_kv_heads)
+            mixed = o.reshape(B, C, -1) @ pa["wo"][oi]
+        x, _ = G.post(x, mixed, p, li, cfg, keep)
+    idx = jnp.maximum(clens - 1, 0)
+    x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+    return pool_k, pool_v, G.logits(p, cfg, x_last), new_ssm, new_tail
+
+
+def _pattern_decode(p, last_tok, lens, pool_k, pool_v, table, ssm_state,
+                    conv_tail, cfg: ModelConfig):
+    """Decode step of a layer-pattern model: the chunk step's layers
+    for one token per row.  Padded rows (table −1) route nowhere.
+    Returns (pool_k, pool_v, (logits, tokens routed to each held
+    expert [layers, held]), new_ssm, new_tail)."""
+    B = last_tok.shape[0]
+    x = G.embed(p, cfg, last_tok)                         # [B,d]
+    pos = (lens - 1).astype(jnp.int32)
+    keep = table[:, 0] >= 0
+    new_ssm, new_tail = ssm_state, conv_tail
+    loads = []
+    for li, (kind, oi) in enumerate(zip(cfg.layer_types,
+                                        G.mixer_ordinals(cfg))):
+        h = G.pre(x, p, li, cfg)
+        if kind == "mamba":
+            mixed, tail_i, st_i = M2.mamba2_decode_step(
+                h, p["mamba"], oi, cfg, new_tail[oi], new_ssm[oi])
+            new_ssm = new_ssm.at[oi].set(st_i)
+            new_tail = new_tail.at[oi].set(tail_i)
+        else:
+            pa = p["attn"]
+            q, k, v = attn_qkv(h[:, None, :], pa, oi, cfg, pos[:, None])
+            pool_k, pool_v = cache_ops.write_tokens(
+                pool_k, pool_v, k, v, table, pos, oi, cfg.n_kv_heads)
+            o = cache_ops.paged_decode_attention(
+                q[:, 0], pool_k, pool_v, table, lens, oi, cfg.n_kv_heads)
+            mixed = o.reshape(B, -1) @ pa["wo"][oi]
+        x, load = G.post(x, mixed, p, li, cfg, keep)
+        loads.append(load)
+    return (pool_k, pool_v, (G.logits(p, cfg, x), jnp.stack(loads)),
+            new_ssm, new_tail)
 
 
 def _prefill_chunk_ssm_impl(params, midx, toks, clens, ssm_state, conv_tail,
@@ -1026,7 +1134,9 @@ def _prefill_chunk_ssm_impl(params, midx, toks, clens, ssm_state, conv_tail,
 
 def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
                   cfg: ModelConfig):
-    """Prefill: full causal forward, write KV/state caches, last logits."""
+    """Prefill: full causal forward, write KV/state caches, last logits.
+    A layer-pattern model runs its chunk step over the whole prompt
+    from zero state."""
     _note_trace("prefill")
     p = _select_model(params, midx)
     B, S = toks.shape
@@ -1039,10 +1149,13 @@ def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
     if cfg.ssm:
         sc = cfg.ssm
         conv_dim = cfg.d_inner + 2 * sc.n_groups * sc.d_state
-        new_ssm = jnp.zeros((cfg.n_layers, B, cfg.n_ssm_heads, sc.head_dim,
-                             sc.d_state), jnp.float32)
-        new_tail = jnp.zeros((cfg.n_layers, B, sc.conv_kernel - 1, conv_dim),
-                             x.dtype)
+        new_ssm = jnp.zeros((cfg.n_ssm_layers, B, cfg.n_ssm_heads,
+                             sc.head_dim, sc.d_state), jnp.float32)
+        new_tail = jnp.zeros((cfg.n_ssm_layers, B, sc.conv_kernel - 1,
+                              conv_dim), x.dtype)
+    if cfg.layer_types is not None:
+        return _pattern_chunk(p, toks, jnp.zeros((B,), jnp.int32), lens,
+                              pool_k, pool_v, table, new_ssm, new_tail, cfg)
 
     def attn_layer(x, li, attn_li, lp_attn, pool_k, pool_v):
         h = rms_norm(x, lp_attn["ln1"][li], cfg.rms_eps)
@@ -1064,8 +1177,8 @@ def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
             attn_li += 1
             h = rms_norm(x, lp["ln2"][li], cfg.rms_eps)
             if cfg.family == "moe":
-                out, _ = MoE.moe_ffn_dropless(h, lp, li, cfg)
-                x = x + out
+                keep = positions < lens[:, None]
+                x = x + MoE.moe_ffn_held(h, lp, li, cfg, keep)[0]
             else:
                 x = x + mlp(h, lp, li)
         else:  # ssm / hybrid
@@ -1096,10 +1209,16 @@ def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
     """One decode step: write KV of current token, attend, next logits.
 
     ``lens`` includes the current token (its slot is already reserved);
-    its position is lens-1.
+    its position is lens-1.  An MoE model returns, in place of the
+    logits, (logits, tokens routed to each held expert [MoE layers,
+    held]); padded rows (table −1) route nowhere.  A layer-pattern
+    model runs ``_pattern_decode``.
     """
     _note_trace("decode")
     p = _select_model(params, midx)
+    if cfg.layer_types is not None:
+        return _pattern_decode(p, last_tok, lens, pool_k, pool_v, table,
+                               ssm_state, conv_tail, cfg)
     B = last_tok.shape[0]
     x = p["tok"]["embed"][last_tok]                         # [B,d]
     pos = (lens - 1).astype(jnp.int32)
@@ -1121,14 +1240,17 @@ def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
         return x, pool_k, pool_v
 
     attn_li = 0
+    loads = []
     for li in range(cfg.n_layers):
         if cfg.family in ("dense", "vlm", "audio", "moe"):
             x, pool_k, pool_v = attn_layer(x, li, attn_li, lp, pool_k, pool_v)
             attn_li += 1
             h = rms_norm(x, lp["ln2"][li], cfg.rms_eps)
             if cfg.family == "moe":
-                out, _ = MoE.moe_ffn_dropless(h[:, None, :], lp, li, cfg)
-                x = x + out[:, 0]
+                out, _, load = MoE.moe_ffn_held(h, lp, li, cfg,
+                                                table[:, 0] >= 0)
+                x = x + out
+                loads.append(load)
             else:
                 x = x + mlp(h, lp, li)
         else:
@@ -1147,6 +1269,8 @@ def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
                 x = x + mlp(h2, sa, 0)
 
     logits = lm_logits(x, p["tok"], cfg)[..., :cfg.vocab_size]
+    if loads:
+        logits = (logits, jnp.stack(loads))
     return pool_k, pool_v, logits, new_ssm, new_tail
 
 
@@ -1277,6 +1401,11 @@ _IMPL_TABLE = {
     "fused_decode": (_fused_decode_impl, (3, 4)),
     "fused_prefill_chunk": (_fused_prefill_chunk_impl, (4, 5)),
 }
+# a layer-pattern model's decode and chunk steps also consume the SSM
+# state and conv tail gathered for them, as the pure-SSM chunk step
+# does: at its size (1.21 GB for granite's 16 slots) a second copy
+# would crowd the chip
+_STATE_ARGS = {"decode": (7, 8), "chunk": (8, 9)}
 
 
 @lru_cache(maxsize=None)
@@ -1287,6 +1416,8 @@ def jitted_step(kind: str, cfg_key: ModelConfig):
     colocated instances of one architecture recompile identical
     programs N times."""
     impl, donate = _IMPL_TABLE[kind]
+    if cfg_key.layer_types is not None:
+        donate += _STATE_ARGS.get(kind, ())
     # keep the impl's name on the partial: jit names the program (and
     # its compile events and profiler spans) after it
     step = partial(impl, cfg=cfg_key)

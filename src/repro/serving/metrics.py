@@ -462,6 +462,7 @@ class SpanView:
     parent: Any
     t0: Any
     t1: Any
+    values: Dict[int, Any] = field(default_factory=dict)  # entry → value
 
 
 class SpanRecord:
@@ -473,7 +474,9 @@ class SpanRecord:
     recording allocates nothing the garbage collector walks.  While the
     profiler runs, each span also opens a ``jax.profiler.TraceAnnotation``
     of the same name (imported with the first handle), which puts it on
-    the device trace's timeline.  ``event(name)`` records an instant.
+    the device trace's timeline.  ``event(name)`` records an instant,
+    ``event(name, value)`` one that carries a value (a step's counters,
+    kept by entry in ``values``).
     Besides the entries, ``seconds[nid]`` and ``count[nid]`` total each
     name's closed spans (and events); they never overflow, and are what
     ``ServingMetrics`` exports.
@@ -515,6 +518,7 @@ class SpanRecord:
         """Drop every entry and total; spans open now close unrecorded."""
         self.n = 0
         self.overflowed = False
+        self.values: Dict[int, Any] = {}
         self._stack = [-1] * len(self._stack)
         self._t0 = [_NAN] * len(self._t0)
         self.seconds = array("d", bytes(8 * len(self.names)))
@@ -536,8 +540,9 @@ class SpanRecord:
             self.count.append(0)
         return h
 
-    def event(self, name: str) -> None:
-        """Record an instant inside the innermost open span."""
+    def event(self, name: str, value: Any = None) -> None:
+        """Record an instant inside the innermost open span, with
+        ``value`` (if given) kept beside it."""
         nid = self.handle(name).nid
         self.count[nid] += 1
         i = self.n
@@ -546,6 +551,8 @@ class SpanRecord:
             self.parent[i] = self._stack[-1]
             self.t0[i] = self.t1[i] = self.clock()
             self.n = i + 1
+            if value is not None:
+                self.values[i] = value
         else:
             self.overflowed = True
         if self._profiling():
@@ -562,7 +569,8 @@ class SpanRecord:
                         np.array(self.name[:n], np.int32),
                         np.array(self.parent[:n], np.int32),
                         np.array(self.t0[:n], np.float64),
-                        np.array(self.t1[:n], np.float64))
+                        np.array(self.t1[:n], np.float64),
+                        dict(self.values))
 
 
 # the serving path's record (one per process, as ``engine.TRACE_COUNTS``)
@@ -575,8 +583,8 @@ def span(name: str) -> _Span:
     return SPANS.handle(name)
 
 
-def event(name: str) -> None:
-    SPANS.event(name)
+def event(name: str, value: Any = None) -> None:
+    SPANS.event(name, value)
 
 
 class ServingMetrics:

@@ -23,7 +23,8 @@ def _engine(arch, quota=100_000, n_blocks=200_000, max_slots=4, seed=0):
     return eng, pool, cfg, params
 
 
-@pytest.mark.parametrize("arch", ["qwen2-7b", "zamba2-1.2b", "mamba2-2.7b"])
+@pytest.mark.parametrize("arch", ["qwen2-7b", "zamba2-1.2b", "mamba2-2.7b",
+                                  "granite-4.0-h-small"])
 def test_engine_generates_greedy_match(arch):
     """Engine prefill+decode (paged pool) == full-forward greedy."""
     eng, pool, cfg, params = _engine(arch)

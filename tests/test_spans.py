@@ -207,6 +207,21 @@ def test_record_is_bounded_and_says_so():
     assert not rec.overflowed and rec.view().name.size == 0
 
 
+def test_an_event_keeps_its_value_by_entry():
+    """A valued event (an MoE decode step's expert counts) is kept by
+    its entry, under the span it fired in, until the next session."""
+    rec = SpanRecord(capacity=8)
+    rec.install(LogicalClock())
+    with rec.handle("step"):
+        rec.event("plain")
+        rec.event("counts", np.array([[2, 0], [1, 3]]))
+    view = rec.view()
+    assert list(view.values) == [2] and view.parent[2] == 0
+    np.testing.assert_array_equal(view.values[2], [[2, 0], [1, 3]])
+    rec.install(LogicalClock())
+    assert rec.view().values == {}
+
+
 def test_totals_by_name_outlive_the_record():
     """The per-name totals, which the operator's counters export, go
     on after the record of entries is full, and only closed spans

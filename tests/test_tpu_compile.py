@@ -17,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.config import BLOCK_TOKENS, replace
+from repro.configs.granite_4p0_h_small import CONFIG as GRANITE
 from repro.configs.phi_3_vision_4p2b import CONFIG as PHI3
 from repro.kernels.flash_prefill import (flash_prefill,
                                          fused_paged_flash_prefill)
@@ -167,3 +168,52 @@ def test_step_writes_the_arena_in_place(chip, kind, hd):
     assert {lay for lay, _ in ops} == {row_major}, ops
     assert " while(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+# granite-4.0-h-small at published widths: one period of its layer
+# pattern (9 Mamba-2 layers, 1 attention layer), 9 of the 72 experts
+# held (one chip's share of eight), 16 rows over the benchmark cell's
+# arena (16 slots x 64 token-blocks x 2 attention layers x 8 KV heads)
+GRANITE_ROWS, GRANITE_ARENA = 16, 16_384
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_granite_step_gathers_no_expert_weights_per_token(chip, kind):
+    """The held-expert layer reads each expert's weights where they
+    lie: no temporary comes near a per-token gather of the routed
+    experts' weights ([T, k, d, f_e]), and a decode step's temporaries
+    are below one layer's held experts (a layer's slice of the stacked
+    expert weights is never copied out for the grouped matmul)."""
+    cfg = replace(GRANITE, name="", n_layers=10,
+                  layer_types=GRANITE.layer_types[:10],
+                  moe=replace(GRANITE.moe, held_count=9))
+    mc, sc, b = cfg.moe, cfg.ssm, GRANITE_ROWS
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_stacked_params(k, cfg, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    pool = spec((GRANITE_ARENA, *arena_block_shape(BLOCK_TOKENS, cfg.hd)),
+                jnp.bfloat16)
+    i32 = jnp.int32
+    state = (spec((cfg.n_ssm_layers, b, cfg.n_ssm_heads, sc.head_dim,
+                   sc.d_state), jnp.float32),
+             spec((cfg.n_ssm_layers, b, sc.conv_kernel - 1,
+                   cfg.d_inner + 2 * sc.n_groups * sc.d_state),
+                  jnp.bfloat16))
+    table = spec((b, STEP_TABLE), i32)
+    if kind == "decode":
+        tokens = b
+        args = (spec((b,), i32), spec((b,), i32), pool, pool, table)
+    else:
+        tokens = b * STEP_CHUNK
+        args = (spec((b, STEP_CHUNK), i32), spec((b,), i32),
+                spec((b,), i32), pool, pool, table)
+    compiled = jitted_step(kind, cfg).lower(
+        params, spec((), i32), *args, *state).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < tokens * mc.top_k * cfg.d_model * mc.d_expert * 2
+    if kind == "decode":
+        assert temp < mc.n_held * 3 * cfg.d_model * mc.d_expert * 2
