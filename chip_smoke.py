@@ -210,10 +210,9 @@ def engine_prefill_logits(eng, prompt):
     C, S = eng.chunk_tokens, len(prompt)
     out = {}
     if eng.cfg.ssm:
-        st = jnp.zeros(eng.ssm_state.shape[:1] + (1,)
-                       + eng.ssm_state.shape[2:], eng.ssm_state.dtype)
-        tail = jnp.zeros(eng.conv_tail.shape[:1] + (1,)
-                         + eng.conv_tail.shape[2:], eng.conv_tail.dtype)
+        # the probe's state: slot 0 of the drained engine, named by the
+        # table's one column (a pure-SSM model has no KV blocks)
+        table = jnp.zeros((1, 1), jnp.int32)
     else:
         view, pool = eng.view, eng.pool
         sid = -1                      # probe sequence; the view is empty
@@ -224,14 +223,16 @@ def engine_prefill_logits(eng, prompt):
         n = min(C, S - off)
         toks = np.zeros((1, C), np.int32)
         toks[0, :n] = prompt[off:off + n]
-        toks, clens = jnp.asarray(toks), jnp.asarray([n], jnp.int32)
+        toks, offs = jnp.asarray(toks), jnp.asarray([off], jnp.int32)
+        clens = jnp.asarray([n], jnp.int32)
         if eng.cfg.ssm:
-            logits, st, tail = eng._chunk_fn(eng.params, eng.model_index,
-                                             toks, clens, st, tail)
+            logits, eng.ssm_state, eng.conv_tail = eng._chunk_fn(
+                eng.params, eng.model_index, toks, offs, clens, table,
+                eng.ssm_state, eng.conv_tail)
         else:
             pool.k, pool.v, logits = eng._chunk_fn(
-                eng.params, eng.model_index, toks,
-                jnp.asarray([off], jnp.int32), clens, pool.k, pool.v, table)
+                eng.params, eng.model_index, toks, offs, clens, pool.k,
+                pool.v, table)
         out[off + n - 1] = np.asarray(logits[0], np.float32)
     if not eng.cfg.ssm:
         view.free_seq(sid)

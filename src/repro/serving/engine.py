@@ -29,7 +29,10 @@ by ``benchmarks/fused_tick``.
 The engine manages a fixed number of decode *slots* (continuous
 batching): a sequence occupies a slot from prefill completion until
 finish, and its attention KV lives in the unified pool while SSM state
-(constant-size) lives in per-slot dense arrays.
+(constant-size) lives in per-slot dense arrays.  Those arrays stay
+whole on the device: every step that reads or writes state takes them
+donated, reads and writes its rows' slots inside the program and
+returns them in place, so no step copies them.
 """
 from __future__ import annotations
 
@@ -198,11 +201,11 @@ def total_traces() -> int:
 
 
 # the four host phases of a step, each a child span of the step's span:
-#   prep    host work before the jitted call (tables, padding, transfers
-#           to the device, the SSM state's gathers)
+#   prep    host work before the jitted call (tables, padding, the rows'
+#           next-token reservations, transfers to the device)
 #   launch  the jitted call until it returns (it dispatches, asynchronously)
 #   sync    the host blocked on the step's sampled tokens
-#   commit  ``apply_*_result``, with the SSM state's scatter and rollback
+#   commit  ``apply_*_result``
 STEP_PHASES = ("prep", "launch", "sync", "commit")
 
 
@@ -240,6 +243,9 @@ class DecodeJob:
     reqs: List[Request]
     seq_ids: List[int]
     last_tok: np.ndarray          # [B] int32 — token decoded this step
+    # rows whose next-token block ``Engine.reserve_decode`` could not
+    # reserve: their step commits nothing and keeps their SSM state
+    held: List[int] = field(default_factory=list)
 
     def __len__(self) -> int:
         return len(self.reqs)
@@ -296,6 +302,7 @@ class Engine:
         self.prefill_spans = step_spans("prefill", cfg.name)
         self.admit_span = span(f"mux.admit.{cfg.name}")
         self.moe_event = f"mux.moe.{cfg.name}"
+        self.held_event = f"mux.ssm_held.{cfg.name}"
         # request timestamps (first_token/finish) are stamped from this
         # clock so a deterministic driver can own the time domain
         # (serving/driver.py); MuxScheduler re-points it on all engines
@@ -315,20 +322,24 @@ class Engine:
         self.preempted: List[Request] = []      # evicted by stall escape
         self._prefilling: Dict[int, int] = {}   # slot → next prompt pos
         self._stall_ticks = 0
-        self._rolled_rows: List[int] = []
         self._next_seq = 0
         self._rng = np.random.default_rng(rng_seed)
         # token-emission hook (serving/frontend.py): called as
         # ``emit(event, request, token)`` at every COMMITTED progress
-        # point — "token" (an output token survived its reserve/validate
-        # step; rolled-back tokens never emit), "finish" (request
+        # point — "token" (an output token whose row reserved its next
+        # block; a held row's token never emits), "finish" (request
         # finalized), "reset" (an eviction cleared the request's
         # progress; previously streamed tokens are void).  Installed by
         # ``MuxScheduler.set_emit`` (which re-applies it to engines
         # rebuilt by crash recovery); None = no streaming consumer.
         self.emit: Optional[Callable[[str, Request, int], None]] = None
 
-        # SSM per-slot state, one entry per SSM layer
+        # SSM per-slot state, one entry per SSM layer, held whole: the
+        # steps take it donated and index their rows' slots inside.  A
+        # slot's conv tail is one flat row of its K-1 positions, so the
+        # chip lays the array out row-major (a [.., K-1, conv] array
+        # gets its slots laid minor to the K-1 axis, which every step
+        # would copy out of and back)
         if cfg.ssm:
             sc = cfg.ssm
             conv_dim = cfg.d_inner + 2 * sc.n_groups * sc.d_state
@@ -336,7 +347,7 @@ class Engine:
                 (cfg.n_ssm_layers, max_slots, cfg.n_ssm_heads, sc.head_dim,
                  sc.d_state), jnp.float32)
             self.conv_tail = jnp.zeros(
-                (cfg.n_ssm_layers, max_slots, sc.conv_kernel - 1, conv_dim),
+                (cfg.n_ssm_layers, max_slots, (sc.conv_kernel - 1) * conv_dim),
                 jnp.bfloat16 if params["tok"]["embed"].dtype == jnp.bfloat16
                 else params["tok"]["embed"].dtype)
         else:
@@ -509,20 +520,13 @@ class Engine:
             with prep:
                 args, slot_ids, seq_ids = self._prefill_inputs(admitted)
             with launch:
-                pool_k, pool_v, logits, new_ssm, new_tail = \
-                    self._prefill_fn(self.params, self.model_index, *args)
-            B = len(admitted)
-            self.pool.k, self.pool.v = pool_k, pool_v
-            if self.cfg.ssm:
-                with commit:
-                    sl = jnp.asarray(slot_ids)
-                    self.ssm_state = self.ssm_state.at[:, sl].set(
-                        new_ssm[:, :B])
-                    self.conv_tail = self.conv_tail.at[:, sl].set(
-                        new_tail[:, :B].astype(self.conv_tail.dtype))
+                (self.pool.k, self.pool.v, logits, self.ssm_state,
+                 self.conv_tail) = self._prefill_fn(
+                    self.params, self.model_index, *args)
             # sample first token
             with sync:
-                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
+                nxt = np.asarray(jnp.argmax(logits[:len(admitted)],
+                                            axis=-1))
             with commit:
                 return self._commit_prefill(admitted, slot_ids, seq_ids,
                                             nxt)
@@ -554,11 +558,31 @@ class Engine:
             self.slot_seq[slot_ids[i]] = sid
             r._seq_id = sid
 
-        toks, lens, table = _pad_rows(
-            Bp, (toks, 0), (lens, 0),
-            (self.view.block_table(seq_ids, self.max_blocks), -1))
+        toks, lens = _pad_rows(Bp, (toks, 0), (lens, 0))
+        table = self._row_table(
+            self.view.block_table(seq_ids, self.max_blocks), slot_ids, Bp)
         return ((jnp.asarray(toks), jnp.asarray(lens), self.pool.k,
-                 self.pool.v, jnp.asarray(table)), slot_ids, seq_ids)
+                 self.pool.v, jnp.asarray(table)) + self._state(),
+                slot_ids, seq_ids)
+
+    def _row_table(self, table: np.ndarray, slots, rows: int) -> np.ndarray:
+        """The step's rows' cache addresses, padded to ``rows``: the KV
+        block ``table`` (−1 padded), and for a model with SSM layers
+        each row's state slot as one more column (``max_slots`` for a
+        padded row: past the state arrays, so the step's read of it
+        clamps and its write drops)."""
+        table, = _pad_rows(rows, (table, -1))
+        if self.cfg.ssm:
+            slots, = _pad_rows(rows, (np.asarray(slots, np.int32),
+                                      self.max_slots))
+            table = np.concatenate([table, slots[:, None]], axis=1)
+        return table
+
+    def _state(self) -> tuple:
+        """The SSM state and conv tail, whole, as a step's last two
+        arguments (it consumes them and returns them updated); none for
+        a model without SSM layers."""
+        return (self.ssm_state, self.conv_tail) if self.cfg.ssm else ()
 
     def _commit_prefill(self, admitted: List[Request], slot_ids, seq_ids,
                         nxt: np.ndarray) -> int:
@@ -706,26 +730,35 @@ class Engine:
         return done_tokens
 
     def run_chunk_job(self, job: Optional[PrefillJob] = None) -> int:
-        """Advance one chunk job serially (attention families): one
-        jitted step over a power-of-2 row bucket.  ``job`` defaults to
-        the engine's in-flight chunks (``export_prefill_job``)."""
+        """Advance one chunk job serially: one jitted step over a
+        power-of-2 row bucket.  ``job`` defaults to the engine's
+        in-flight chunks (``export_prefill_job``).  Attention layers
+        write and attend the pool's KV; a model with SSM layers carries
+        its rows' state through the chunk inside the step (a row whose
+        chunk starts its prompt starts from zero state)."""
         step, prep, launch, sync, commit = self.prefill_spans
         with step:
             with prep:
                 job = job or self.export_prefill_job()
                 B = len(job)
                 Bp = _next_pow2(B)
-                toks, offs, clens, table = _pad_rows(
-                    Bp, (job.toks, 0), (job.offs, 0), (job.clens, 0),
-                    (self.view.block_table(job.seq_ids, self.max_blocks),
-                     -1))
+                toks, offs, clens = _pad_rows(
+                    Bp, (job.toks, 0), (job.offs, 0), (job.clens, 0))
+                table = self._row_table(self.view.block_table(
+                    job.seq_ids, self.max_blocks), job.slots, Bp)
+                # a pure-SSM model's chunk step has no KV to write
+                arena = () if self.cfg.family == "ssm" else (self.pool.k,
+                                                             self.pool.v)
                 args = (jnp.asarray(toks), jnp.asarray(offs),
-                        jnp.asarray(clens), self.pool.k, self.pool.v,
-                        jnp.asarray(table))
+                        jnp.asarray(clens), *arena, jnp.asarray(table),
+                        *self._state())
             with launch:
-                pool_k, pool_v, logits = self._chunk_fn(
-                    self.params, self.model_index, *args)
-            self.pool.k, self.pool.v = pool_k, pool_v
+                out = self._chunk_fn(self.params, self.model_index, *args)
+            if arena:
+                self.pool.k, self.pool.v = out[:2]
+            logits = out[len(arena)]
+            if self.cfg.ssm:
+                self.ssm_state, self.conv_tail = out[-2:]
             with sync:
                 nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
             with commit:
@@ -737,57 +770,7 @@ class Engine:
         self.admit_chunked(reqs)
         if not self._prefilling:
             return 0
-        if self.cfg.ssm:
-            return self._run_chunk_ssm()
         return self.run_chunk_job()
-
-    def _run_chunk_ssm(self) -> int:
-        """Chunk advance for engines with SSM state: the state and conv
-        tail of the job's slots are gathered, carried through the chunk
-        and scattered back; a layer-pattern model's step also writes
-        and attends its paged KV.  Rows go to a power-of-2 bucket;
-        padded rows name slot ``max_slots``, past the state arrays, so
-        the gather clamps them and the scatter drops them."""
-        step, prep, launch, sync, commit = self.prefill_spans
-        paged = self.cfg.layer_types is not None
-        with step:
-            with prep:
-                job = self.export_prefill_job()
-                B = len(job)
-                slots, toks, offs, clens = _pad_rows(
-                    _next_pow2(B), (np.array(job.slots), self.max_slots),
-                    (job.toks, 0), (job.offs, 0), (job.clens, 0))
-                sl_idx = jnp.asarray(slots)
-                st = self.ssm_state[:, sl_idx]
-                tail = self.conv_tail[:, sl_idx]
-                # fresh sequences start from zero state
-                fresh = jnp.asarray((offs == 0).astype(np.float32))
-                st = st * (1.0 - fresh)[None, :, None, None, None]
-                tail = tail * (1.0 - fresh[None, :, None, None]).astype(
-                    tail.dtype)
-                if paged:
-                    table, = _pad_rows(len(slots), (self.view.block_table(
-                        job.seq_ids, self.max_blocks), -1))
-                    args = (jnp.asarray(toks), jnp.asarray(offs),
-                            jnp.asarray(clens), self.pool.k, self.pool.v,
-                            jnp.asarray(table), st, tail)
-                else:
-                    args = (jnp.asarray(toks), jnp.asarray(clens), st,
-                            tail)
-            with launch:
-                out = self._chunk_fn(self.params, self.model_index, *args)
-            if paged:
-                self.pool.k, self.pool.v, logits, new_st, new_tail = out
-            else:
-                logits, new_st, new_tail = out
-            with commit:
-                self.ssm_state = self.ssm_state.at[:, sl_idx].set(new_st)
-                self.conv_tail = self.conv_tail.at[:, sl_idx].set(
-                    new_tail.astype(self.conv_tail.dtype))
-            with sync:
-                nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
-            with commit:
-                return self.apply_prefill_result(job, nxt)
 
     # ------------------------------------------------------------------
     def export_decode_job(self) -> Optional[DecodeJob]:
@@ -805,58 +788,50 @@ class Engine:
         return DecodeJob(slots=act, reqs=reqs,
                          seq_ids=[r._seq_id for r in reqs], last_tok=last)
 
+    def reserve_decode(self, job: DecodeJob) -> None:
+        """Reserve each row's next-token block before its step runs, in
+        row order; a row the step will finish reserves nothing.  A row
+        that cannot reserve is held (``job.held``): quota overcommit
+        (admitted sequences' future growth is not reserved, and
+        adapt_quotas may shrink the quota) must not desync lens/pos and
+        corrupt the sequence's KV on the next step, so its token is
+        dropped after the step and retried next tick at the same
+        position — lens is unchanged, the KV rewrite is idempotent,
+        greedy gives the same token once another sequence frees blocks.
+        Its SSM state is not written (the step sees its slot as
+        ``max_slots``): the carry is not idempotent, and re-advancing
+        it on retry would change the eventually committed token."""
+        job.held = [i for i, r in enumerate(job.reqs)
+                    if len(r.output) + 1 < r.max_new_tokens
+                    and not self.view.append_tokens(job.seq_ids[i], 1)]
+        if job.held and self.cfg.ssm:
+            event(self.held_event, len(job.held))
+
     def apply_decode_result(self, job: DecodeJob, nxt: np.ndarray) -> int:
         """Commit one decode step's sampled tokens back into engine and
-        pool bookkeeping (shared by the serial and fused paths).
-
-        Rows that cannot reserve their next-token block are rolled back
-        (indices recorded in ``self._rolled_rows`` for the caller to
-        revert any non-idempotent per-step state, e.g. SSM carries).
-        """
+        pool bookkeeping (shared by the serial and fused paths).  The
+        rows ``reserve_decode`` held commit nothing."""
         done_tokens = 0
-        self._rolled_rows = []
+        held = set(job.held)
         for i, r in enumerate(job.reqs):
+            if i in held:
+                continue
             r.output.append(int(nxt[i]))
             done_tokens += 1
+            if r.first_token < 0:
+                # prefill's first token rolled back on overcommit and
+                # decode regenerated it — TTFT ends here
+                r.first_token = self.clock()
+            if self.emit is not None:
+                self.emit("token", r, int(nxt[i]))
             if r.done:
-                if r.first_token < 0:
-                    # prefill's first token rolled back on overcommit
-                    # and decode regenerated it — TTFT ends here
-                    r.first_token = self.clock()
-                if self.emit is not None:
-                    self.emit("token", r, int(nxt[i]))
                 self._finish_slot(job.slots[i], r)
-            else:
-                ok = self.view.append_tokens(job.seq_ids[i], 1)
-                if ok:
-                    if r.first_token < 0:
-                        r.first_token = self.clock()
-                    # emit only AFTER the reserve validated: a token that
-                    # rolls back below was never committed and must not
-                    # reach a stream
-                    if self.emit is not None:
-                        self.emit("token", r, int(nxt[i]))
-                if not ok:
-                    # quota overcommit (admitted sequences' future
-                    # growth is not reserved, and adapt_quotas may
-                    # shrink the quota): a silent miss here would
-                    # desync lens/pos and corrupt the sequence's KV on
-                    # the next step.  Instead roll the token back and
-                    # retry next tick — lens is unchanged, so the
-                    # retry recomputes the same position (greedy ⇒ the
-                    # same token) once another sequence frees blocks.
-                    # The KV rewrite is idempotent; decode() reverts
-                    # SSM state for rolled-back rows.
-                    r.output.pop()
-                    done_tokens -= 1
-                    self._rolled_rows.append(i)
-        # stall escape: if EVERY row rolled back and nothing finished,
+        # stall escape: if EVERY row was held and nothing finished,
         # no sequence can ever free blocks for the others — after two
         # such ticks, preempt the youngest sequence (evict its cache,
         # restart it from scratch via the scheduler queue; greedy ⇒ it
         # regenerates the same tokens) so the rest can proceed.
-        rollbacks = len(self._rolled_rows)
-        if rollbacks and rollbacks == len(job.reqs):
+        if held and len(held) == len(job.reqs):
             self._stall_ticks += 1
             if self._stall_ticks >= 2:
                 self._preempt_youngest()
@@ -896,35 +871,25 @@ class Engine:
                 if job is None:
                     return 0
                 B = len(job)
-                # lengths include the reserved current token
+                # lengths include the reserved current token; read
+                # before the next one is reserved
                 lens = self.view.seq_lens(job.seq_ids)
                 table = self.view.block_table(job.seq_ids, self.max_blocks)
-                last_tok = job.last_tok
-                slots = np.array(job.slots)
+                self.reserve_decode(job)
+                slots = np.array(job.slots, np.int32)
+                slots[job.held] = self.max_slots
                 # power-of-2 row bucket (padded rows: len 1, table −1 —
-                # one masked garbage softmax, discarded below — and slot
-                # ``max_slots``, past the SSM state: its gather clamps,
-                # its scatter drops)
+                # one masked garbage softmax, discarded below)
                 Bp = _next_pow2(B)
-                if Bp != B:
-                    last_tok, lens, table, slots = _pad_rows(
-                        Bp, (job.last_tok, 0), (lens, 1), (table, -1),
-                        (slots, self.max_slots))
-                sl = jnp.asarray(slots)
-                ssm_state = self.ssm_state[:, sl] if self.cfg.ssm else None
-                conv_tail = self.conv_tail[:, sl] if self.cfg.ssm else None
+                last_tok, lens = _pad_rows(Bp, (job.last_tok, 0), (lens, 1))
                 args = (jnp.asarray(last_tok), jnp.asarray(lens),
-                        self.pool.k, self.pool.v, jnp.asarray(table),
-                        ssm_state, conv_tail)
+                        self.pool.k, self.pool.v,
+                        jnp.asarray(self._row_table(table, slots, Bp)),
+                        self.ssm_state, self.conv_tail)
             with launch:
-                pool_k, pool_v, logits, new_ssm, new_tail = self._decode_fn(
+                (self.pool.k, self.pool.v, logits, self.ssm_state,
+                 self.conv_tail) = self._decode_fn(
                     self.params, self.model_index, *args)
-            self.pool.k, self.pool.v = pool_k, pool_v
-            if self.cfg.ssm:
-                with commit:
-                    prev_ssm, prev_tail = self.ssm_state, self.conv_tail
-                    self.ssm_state = self.ssm_state.at[:, sl].set(new_ssm)
-                    self.conv_tail = self.conv_tail.at[:, sl].set(new_tail)
             with sync:
                 if self.cfg.moe:
                     # with the tokens, in one transfer: the tokens routed
@@ -937,19 +902,7 @@ class Engine:
                 else:
                     nxt = np.asarray(jnp.argmax(logits[:B], axis=-1))
             with commit:
-                toks = self.apply_decode_result(job, nxt)
-                if self.cfg.ssm and self._rolled_rows:
-                    # rolled-back rows must retry from the PRE-step state:
-                    # the SSM carry is not idempotent (re-advancing it on
-                    # retry would silently change the eventually-committed
-                    # token)
-                    rs = jnp.asarray(np.array([job.slots[i]
-                                               for i in self._rolled_rows]))
-                    self.ssm_state = self.ssm_state.at[:, rs].set(
-                        prev_ssm[:, rs])
-                    self.conv_tail = self.conv_tail.at[:, rs].set(
-                        prev_tail[:, rs])
-            return toks
+                return self.apply_decode_result(job, nxt)
 
     def has_decode_work(self) -> bool:
         return any(s not in self._prefilling for s in self.active_slots())
@@ -992,6 +945,38 @@ class Engine:
 # the program (``_select_model``), so fused-group members and the M=1
 # singleton case run off the same buffers with zero weight copies.
 # ---------------------------------------------------------------------------
+# SSM state inside a step.  A model with SSM layers keeps each layer's
+# state [L, slots, H, P, N] and conv tail [L, slots, (K-1)·conv] whole;
+# the step gets its rows' slots as the block table's last column
+# (``Engine._row_table``), reads each layer's rows where they lie and
+# writes them back in place.  A slot past the arrays (a padded row, or a
+# row whose step is held) reads clamped and writes nothing.
+def _split_table(table):
+    """(KV block table, state slots) of an SSM model's row table."""
+    return table[:, :-1], table[:, -1]
+
+
+def _read_state(ssm_state, conv_tail, i, slots, cfg: ModelConfig,
+                fresh=None):
+    """SSM layer ``i``'s state and conv tail [rows, K-1, conv] at
+    ``slots``; zero in the rows where ``fresh`` (a chunk that starts its
+    prompt)."""
+    st = ssm_state.at[i, slots].get(mode="clip")
+    tail = conv_tail.at[i, slots].get(mode="clip").reshape(
+        len(slots), cfg.ssm.conv_kernel - 1, -1)
+    if fresh is not None:
+        st = jnp.where(fresh[:, None, None, None], 0, st)
+        tail = jnp.where(fresh[:, None, None], 0, tail)
+    return st, tail
+
+
+def _write_state(ssm_state, conv_tail, i, slots, st, tail):
+    """SSM layer ``i``'s new rows written back at ``slots``."""
+    tail = tail.reshape(len(slots), -1).astype(conv_tail.dtype)
+    return (ssm_state.at[i, slots].set(st, mode="drop"),
+            conv_tail.at[i, slots].set(tail, mode="drop"))
+
+
 def _prefill_chunk_impl(params, midx, toks, offs, clens, pool_k, pool_v,
                         table, ssm_state=None, conv_tail=None, *,
                         cfg: ModelConfig):
@@ -1004,8 +989,9 @@ def _prefill_chunk_impl(params, midx, toks, offs, clens, pool_k, pool_v,
     _note_trace("prefill_chunk")
     p = _select_model(params, midx)
     if cfg.layer_types is not None:
-        return _pattern_chunk(p, toks, offs, clens, pool_k, pool_v, table,
-                              ssm_state, conv_tail, cfg)
+        return _pattern_chunk(p, toks, offs, clens, pool_k, pool_v,
+                              *_split_table(table), ssm_state, conv_tail,
+                              cfg)
     B, C = toks.shape
     x = p["tok"]["embed"][toks]
     positions = offs[:, None] + jnp.arange(C)[None, :]
@@ -1034,29 +1020,32 @@ def _prefill_chunk_impl(params, midx, toks, offs, clens, pool_k, pool_v,
     return pool_k, pool_v, logits
 
 
-def _pattern_chunk(p, toks, offs, clens, pool_k, pool_v, table, ssm_state,
-                   conv_tail, cfg: ModelConfig):
+def _pattern_chunk(p, toks, offs, clens, pool_k, pool_v, table, slots,
+                   ssm_state, conv_tail, cfg: ModelConfig):
     """Chunk step of a layer-pattern model (``models/granite.py``): each
-    layer's mixer by the pattern — Mamba-2 carries the SSM state and
-    conv tail at its SSM-layer ordinal through the chunk (``clens``
-    freezes them past a row's true length), attention writes the
-    chunk's KV at its attention-layer ordinal and attends everything
-    written so far — then the MoE FFN, padded positions routed nowhere.
-    Returns (pool_k, pool_v, logits, new_ssm, new_tail)."""
+    layer's mixer by the pattern — Mamba-2 carries its rows' SSM state
+    and conv tail at its SSM-layer ordinal through the chunk (from zero
+    where the chunk starts the prompt; ``clens`` freezes them past a
+    row's true length), attention writes the chunk's KV at its
+    attention-layer ordinal and attends everything written so far —
+    then the MoE FFN, padded positions routed nowhere.
+    Returns (pool_k, pool_v, logits, ssm_state, conv_tail)."""
     B, C = toks.shape
     x = G.embed(p, cfg, toks)
     positions = offs[:, None] + jnp.arange(C)[None, :]
     keep = jnp.arange(C)[None, :] < clens[:, None]
-    new_ssm, new_tail = ssm_state, conv_tail
+    fresh = offs == 0
     for li, (kind, oi) in enumerate(zip(cfg.layer_types,
                                         G.mixer_ordinals(cfg))):
         h = G.pre(x, p, li, cfg)
         if kind == "mamba":
+            st, tail = _read_state(ssm_state, conv_tail, oi, slots, cfg,
+                                   fresh)
             mixed, st, tail = M2.mamba2_mixer(
-                h, p["mamba"], oi, cfg, conv_tail=new_tail[oi],
-                ssm_state=new_ssm[oi], return_cache=True, length_mask=keep)
-            new_ssm = new_ssm.at[oi].set(st)
-            new_tail = new_tail.at[oi].set(tail.astype(new_tail.dtype))
+                h, p["mamba"], oi, cfg, conv_tail=tail, ssm_state=st,
+                return_cache=True, length_mask=keep)
+            ssm_state, conv_tail = _write_state(ssm_state, conv_tail, oi,
+                                                slots, st, tail)
         else:
             pa = p["attn"]
             q, k, v = attn_qkv(h, pa, oi, cfg, positions)
@@ -1068,29 +1057,29 @@ def _pattern_chunk(p, toks, offs, clens, pool_k, pool_v, table, ssm_state,
         x, _ = G.post(x, mixed, p, li, cfg, keep)
     idx = jnp.maximum(clens - 1, 0)
     x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    return pool_k, pool_v, G.logits(p, cfg, x_last), new_ssm, new_tail
+    return pool_k, pool_v, G.logits(p, cfg, x_last), ssm_state, conv_tail
 
 
-def _pattern_decode(p, last_tok, lens, pool_k, pool_v, table, ssm_state,
-                    conv_tail, cfg: ModelConfig):
+def _pattern_decode(p, last_tok, lens, pool_k, pool_v, table, slots,
+                    ssm_state, conv_tail, cfg: ModelConfig):
     """Decode step of a layer-pattern model: the chunk step's layers
     for one token per row.  Padded rows (table −1) route nowhere.
     Returns (pool_k, pool_v, (logits, tokens routed to each held
-    expert [layers, held]), new_ssm, new_tail)."""
+    expert [layers, held]), ssm_state, conv_tail)."""
     B = last_tok.shape[0]
     x = G.embed(p, cfg, last_tok)                         # [B,d]
     pos = (lens - 1).astype(jnp.int32)
     keep = table[:, 0] >= 0
-    new_ssm, new_tail = ssm_state, conv_tail
     loads = []
     for li, (kind, oi) in enumerate(zip(cfg.layer_types,
                                         G.mixer_ordinals(cfg))):
         h = G.pre(x, p, li, cfg)
         if kind == "mamba":
-            mixed, tail_i, st_i = M2.mamba2_decode_step(
-                h, p["mamba"], oi, cfg, new_tail[oi], new_ssm[oi])
-            new_ssm = new_ssm.at[oi].set(st_i)
-            new_tail = new_tail.at[oi].set(tail_i)
+            st, tail = _read_state(ssm_state, conv_tail, oi, slots, cfg)
+            mixed, tail, st = M2.mamba2_decode_step(
+                h, p["mamba"], oi, cfg, tail, st)
+            ssm_state, conv_tail = _write_state(ssm_state, conv_tail, oi,
+                                                slots, st, tail)
         else:
             pa = p["attn"]
             q, k, v = attn_qkv(h[:, None, :], pa, oi, cfg, pos[:, None])
@@ -1102,41 +1091,45 @@ def _pattern_decode(p, last_tok, lens, pool_k, pool_v, table, ssm_state,
         x, load = G.post(x, mixed, p, li, cfg, keep)
         loads.append(load)
     return (pool_k, pool_v, (G.logits(p, cfg, x), jnp.stack(loads)),
-            new_ssm, new_tail)
+            ssm_state, conv_tail)
 
 
-def _prefill_chunk_ssm_impl(params, midx, toks, clens, ssm_state, conv_tail,
-                            *, cfg: ModelConfig):
+def _prefill_chunk_ssm_impl(params, midx, toks, offs, clens, table,
+                            ssm_state, conv_tail, *, cfg: ModelConfig):
     """Chunked prefill for pure-SSM models: the mixer's conv-tail +
-    state carry IS the chunk boundary.  ``clens`` masks padded chunk
-    positions (dt=0 ⇒ state frozen past the true chunk length)."""
+    state carry IS the chunk boundary.  Of ``table`` only the rows'
+    slots are read (``_split_table``: no KV blocks); a chunk at offset 0
+    starts from zero state.  ``clens`` masks padded chunk positions
+    (dt=0 ⇒ state frozen past the true chunk length)."""
     _note_trace("prefill_chunk_ssm")
     p = _select_model(params, midx)
     B, C = toks.shape
     x = p["tok"]["embed"][toks]
     mask = jnp.arange(C)[None, :] < clens[:, None]
+    _, slots = _split_table(table)
     lp = p["layers"]
-    new_ssm = ssm_state
-    new_tail = conv_tail
     for li in range(cfg.n_layers):
         h = rms_norm(x, lp["ln1"][li], cfg.rms_eps)
+        st, tail = _read_state(ssm_state, conv_tail, li, slots, cfg,
+                               offs == 0)
         out, st, tail = M2.mamba2_mixer(
-            h, lp, li, cfg, conv_tail=conv_tail[li],
-            ssm_state=ssm_state[li], return_cache=True, length_mask=mask)
+            h, lp, li, cfg, conv_tail=tail, ssm_state=st,
+            return_cache=True, length_mask=mask)
         x = x + out
-        new_ssm = new_ssm.at[li].set(st)
-        new_tail = new_tail.at[li].set(tail.astype(new_tail.dtype))
+        ssm_state, conv_tail = _write_state(ssm_state, conv_tail, li, slots,
+                                            st, tail)
     idx = jnp.maximum(clens - 1, 0)
     x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     logits = lm_logits(x_last, p["tok"], cfg)[..., :cfg.vocab_size]
-    return logits, new_ssm, new_tail
+    return logits, ssm_state, conv_tail
 
 
-def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
-                  cfg: ModelConfig):
+def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table,
+                  ssm_state=None, conv_tail=None, *, cfg: ModelConfig):
     """Prefill: full causal forward, write KV/state caches, last logits.
-    A layer-pattern model runs its chunk step over the whole prompt
-    from zero state."""
+    A model with SSM layers writes its rows' state at their slots
+    (``_split_table``); a layer-pattern model runs its chunk step over
+    the whole prompt from zero state."""
     _note_trace("prefill")
     p = _select_model(params, midx)
     B, S = toks.shape
@@ -1144,18 +1137,12 @@ def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     lp = p["layers"]
 
-    new_ssm = None
-    new_tail = None
     if cfg.ssm:
-        sc = cfg.ssm
-        conv_dim = cfg.d_inner + 2 * sc.n_groups * sc.d_state
-        new_ssm = jnp.zeros((cfg.n_ssm_layers, B, cfg.n_ssm_heads,
-                             sc.head_dim, sc.d_state), jnp.float32)
-        new_tail = jnp.zeros((cfg.n_ssm_layers, B, sc.conv_kernel - 1,
-                              conv_dim), x.dtype)
+        table, slots = _split_table(table)
     if cfg.layer_types is not None:
         return _pattern_chunk(p, toks, jnp.zeros((B,), jnp.int32), lens,
-                              pool_k, pool_v, table, new_ssm, new_tail, cfg)
+                              pool_k, pool_v, table, slots, ssm_state,
+                              conv_tail, cfg)
 
     def attn_layer(x, li, attn_li, lp_attn, pool_k, pool_v):
         h = rms_norm(x, lp_attn["ln1"][li], cfg.rms_eps)
@@ -1187,8 +1174,8 @@ def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
                 h, lp, li, cfg, return_cache=True,
                 length_mask=positions < lens[:, None])
             x = x + out
-            new_ssm = new_ssm.at[li].set(fstate)
-            new_tail = new_tail.at[li].set(tail.astype(x.dtype))
+            ssm_state, conv_tail = _write_state(ssm_state, conv_tail, li,
+                                                slots, fstate, tail)
             if cfg.family == "hybrid" and (li + 1) % cfg.attn_every == 0:
                 sa = p["shared_attn"]
                 x, pool_k, pool_v = attn_layer(x, 0, attn_li, sa,
@@ -1201,7 +1188,7 @@ def _prefill_impl(params, midx, toks, lens, pool_k, pool_v, table, *,
     idx = jnp.maximum(lens - 1, 0)
     x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     logits = lm_logits(x_last, p["tok"], cfg)[..., :cfg.vocab_size]
-    return pool_k, pool_v, logits, new_ssm, new_tail
+    return pool_k, pool_v, logits, ssm_state, conv_tail
 
 
 def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
@@ -1211,21 +1198,21 @@ def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
     ``lens`` includes the current token (its slot is already reserved);
     its position is lens-1.  An MoE model returns, in place of the
     logits, (logits, tokens routed to each held expert [MoE layers,
-    held]); padded rows (table −1) route nowhere.  A layer-pattern
-    model runs ``_pattern_decode``.
+    held]); padded rows (table −1) route nowhere.  A model with SSM
+    layers advances its rows' state at their slots (``_split_table``);
+    a layer-pattern model runs ``_pattern_decode``.
     """
     _note_trace("decode")
     p = _select_model(params, midx)
+    if cfg.ssm:
+        table, slots = _split_table(table)
     if cfg.layer_types is not None:
         return _pattern_decode(p, last_tok, lens, pool_k, pool_v, table,
-                               ssm_state, conv_tail, cfg)
+                               slots, ssm_state, conv_tail, cfg)
     B = last_tok.shape[0]
     x = p["tok"]["embed"][last_tok]                         # [B,d]
     pos = (lens - 1).astype(jnp.int32)
     lp = p["layers"]
-
-    new_ssm = ssm_state
-    new_tail = conv_tail
 
     def attn_layer(x, li, attn_li, lp_attn, pool_k, pool_v):
         h = rms_norm(x, lp_attn["ln1"][li], cfg.rms_eps)
@@ -1255,11 +1242,11 @@ def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
                 x = x + mlp(h, lp, li)
         else:
             h = rms_norm(x, lp["ln1"][li], cfg.rms_eps)
-            out, tail_i, st_i = M2.mamba2_decode_step(
-                h, lp, li, cfg, conv_tail[li], ssm_state[li])
+            st, tail = _read_state(ssm_state, conv_tail, li, slots, cfg)
+            out, tail, st = M2.mamba2_decode_step(h, lp, li, cfg, tail, st)
             x = x + out
-            new_ssm = new_ssm.at[li].set(st_i)
-            new_tail = new_tail.at[li].set(tail_i)
+            ssm_state, conv_tail = _write_state(ssm_state, conv_tail, li,
+                                                slots, st, tail)
             if cfg.family == "hybrid" and (li + 1) % cfg.attn_every == 0:
                 sa = p["shared_attn"]
                 x, pool_k, pool_v = attn_layer(x, 0, attn_li, sa,
@@ -1271,7 +1258,7 @@ def _decode_impl(params, midx, last_tok, lens, pool_k, pool_v, table,
     logits = lm_logits(x, p["tok"], cfg)[..., :cfg.vocab_size]
     if loads:
         logits = (logits, jnp.stack(loads))
-    return pool_k, pool_v, logits, new_ssm, new_tail
+    return pool_k, pool_v, logits, ssm_state, conv_tail
 
 
 def _fused_decode_impl(params, toks, lens, pool_k, pool_v, tables, *,
@@ -1391,21 +1378,21 @@ def _fused_prefill_chunk_impl(params, toks, offs, clens, pool_k, pool_v,
 # ---------------------------------------------------------------------------
 # shared jit cache
 # ---------------------------------------------------------------------------
-# (impl, donated arg positions).  Donated buffers are the pool arena
-# (or the SSM carry for the ssm chunk step) — consumed and returned.
+# (impl, donated arg positions).  Donated buffers are the pool arena —
+# consumed and returned.
 _IMPL_TABLE = {
     "prefill": (_prefill_impl, (4, 5)),
     "decode": (_decode_impl, (4, 5)),
     "chunk": (_prefill_chunk_impl, (5, 6)),
-    "chunk_ssm": (_prefill_chunk_ssm_impl, (4, 5)),
+    "chunk_ssm": (_prefill_chunk_ssm_impl, ()),
     "fused_decode": (_fused_decode_impl, (3, 4)),
     "fused_prefill_chunk": (_fused_prefill_chunk_impl, (4, 5)),
 }
-# a layer-pattern model's decode and chunk steps also consume the SSM
-# state and conv tail gathered for them, as the pure-SSM chunk step
-# does: at its size (1.21 GB for granite's 16 slots) a second copy
-# would crowd the chip
-_STATE_ARGS = {"decode": (7, 8), "chunk": (8, 9)}
+# a model with SSM layers: every step that reads or writes state also
+# consumes the whole SSM state and conv tail and returns them, written
+# in place (1.21 GB for granite's 16 slots: a copy would crowd the chip)
+_STATE_ARGS = {"prefill": (7, 8), "decode": (7, 8), "chunk": (8, 9),
+               "chunk_ssm": (6, 7)}
 
 
 @lru_cache(maxsize=None)
@@ -1416,7 +1403,7 @@ def jitted_step(kind: str, cfg_key: ModelConfig):
     colocated instances of one architecture recompile identical
     programs N times."""
     impl, donate = _IMPL_TABLE[kind]
-    if cfg_key.layer_types is not None:
+    if cfg_key.ssm:
         donate += _STATE_ARGS.get(kind, ())
     # keep the impl's name on the partial: jit names the program (and
     # its compile events and profiler spans) after it

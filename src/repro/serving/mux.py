@@ -181,6 +181,9 @@ class FusedGroup:
                     [(eng.view, job.seq_ids if job is not None else [])
                      for eng, job in zip(self.engines, jobs)],
                     rows, self.max_blocks)
+                for eng, job in zip(self.engines, jobs):
+                    if job is not None:
+                        eng.reserve_decode(job)
                 args = (jnp.asarray(toks), jnp.asarray(lens), pool.k,
                         pool.v, jnp.asarray(tables))
             with launch:

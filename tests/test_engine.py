@@ -8,8 +8,10 @@ from repro import configs
 from repro.models.transformer import forward, init_params
 from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool
+from repro.serving.metrics import SPANS
 from repro.serving.mux import MuxScheduler
 
+from _rows import serve_rows_out_of_order
 from _weights import with_model_axis
 
 
@@ -40,6 +42,36 @@ def test_engine_generates_greedy_match(arch):
                             moe_dropless=True)
         seq.append(int(jnp.argmax(logits[0, -1])))
     assert req.output == seq[len(prompt):], (req.output, seq[len(prompt):])
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "zamba2-1.2b"])
+def test_ssm_steps_index_their_slots_out_of_order(arch):
+    """Six requests in six slots; those in slots 0, 2 and 5 finish with
+    their prompt, so every later step runs 3 rows (slots 4, 3, 1, in
+    that order) in a bucket of 4.  Each step leaves the other slots'
+    state as it was; a chunk that starts its prompt starts from zero
+    state, whatever the slot held; every request's tokens are the
+    full-forward greedy ones.  mamba2 chunks its prompts, the hybrid
+    prefills them whole."""
+    cfg = configs.get_reduced(arch)
+    params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    pool = UnifiedKVPool(200_000, cfg.hd, dtype=jnp.float32)
+    eng = Engine(cfg, with_model_axis(params),
+                 pool.register_model(cfg, 100_000), max_slots=6,
+                 chunk_tokens=16)
+    rng = np.random.default_rng(5)
+    reqs = [Request(i, cfg.name,
+                    list(rng.integers(1, cfg.vocab_size, int(n))), m)
+            for i, (n, m) in enumerate(zip((12, 40, 9, 35, 21, 14),
+                                           (1, 6, 1, 5, 6, 1)))]
+    rows = serve_rows_out_of_order(eng, reqs)
+    assert 3 in rows and all(r.done for r in reqs)
+    for r in reqs:
+        seq = list(r.prompt)
+        for _ in range(r.max_new_tokens):
+            logits, _ = forward(params, cfg, jnp.asarray([seq]), remat=False)
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert r.output == seq[len(r.prompt):], r.req_id
 
 
 def test_engine_batched_consistency():
@@ -179,51 +211,88 @@ def test_decode_quota_overcommit_rolls_back():
         assert r.output == q.output
 
 
-def test_decode_overcommit_hybrid_state_revert():
-    """Hybrid (SSM + shared attention) under quota overcommit: a
-    rolled-back decode step must also revert the SSM carry, or the
-    retry re-advances the state and commits a different token than an
-    uncontended run."""
-    cfg = configs.get_reduced("zamba2-1.2b")
+def _refuse_odd_positions_once(view):
+    """A pure-SSM model keeps no KV blocks, so its pool never refuses a
+    token.  Refuse instead, the first time it is asked, each one-token
+    reservation that follows an odd number of tokens (the prompts here
+    have even lengths: no prompt's first token is refused)."""
+    refused = set()
+    reserve = view.append_tokens
+
+    def append_tokens(seq_id, n_tokens):
+        at = view.seqs[seq_id].n_tokens if seq_id in view.seqs else 0
+        if n_tokens == 1 and at % 2 and (seq_id, at) not in refused:
+            refused.add((seq_id, at))
+            return False
+        return reserve(seq_id, n_tokens)
+    view.append_tokens = append_tokens
+
+
+def _held_rows(llm):
+    """Rows whose state a decode step held, by the ``mux.ssm_held``
+    events recorded since ``SPANS`` was last cleared."""
+    v = SPANS.view()
+    return sum(v.values[i] for i, n in enumerate(v.name)
+               if v.names[n] == f"mux.ssm_held.{llm}")
+
+
+@pytest.mark.parametrize("arch", ["zamba2-1.2b", "granite-4.0-h-small",
+                                  "mamba2-2.7b"])
+def test_decode_overcommit_hybrid_state_revert(arch):
+    """Under quota overcommit a decode row that cannot reserve its next
+    token is held: its step leaves its SSM state and carry as they
+    were, or the retry re-advances the state and commits a different
+    token than an uncontended run.  ``mux.ssm_held.<llm>`` counts the
+    held rows; an uncontended run records none.  A pure-SSM model's
+    pool never refuses, so its reservations are refused by hand."""
+    cfg = configs.get_reduced(arch)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
     rng = np.random.default_rng(4)
     p1 = list(rng.integers(1, cfg.vocab_size, 14))
     p2 = list(rng.integers(1, cfg.vocab_size, 14))
     max_new = 24
-    # probe the quota analytically: admit r1, leave exactly one more
-    # lifetime of headroom so r2 admits but their growth overcommits
-    probe_pool = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
-    probe = Engine(cfg, with_model_axis(params),
-                   probe_pool.register_model(cfg, 50_000), max_slots=2)
-    pr = Request(0, cfg.name, list(p1), max_new)
-    lifetime = probe.lifetime_blocks(pr)
-    probe.prefill([pr])
-    used_p = probe_pool.views[cfg.name].used
-    assert lifetime > used_p, "need unreserved growth for overcommit"
-    quota = used_p + lifetime
+    quota = 50_000
+    if cfg.family != "ssm":
+        # probe the quota analytically: admit r1, leave exactly one more
+        # lifetime of headroom so r2 admits but their growth overcommits
+        probe_pool = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
+        probe = Engine(cfg, with_model_axis(params),
+                       probe_pool.register_model(cfg, 50_000), max_slots=2)
+        pr = Request(0, cfg.name, list(p1), max_new)
+        lifetime = probe.lifetime_blocks(pr)
+        probe.prefill([pr])
+        used_p = probe_pool.views[cfg.name].used
+        assert lifetime > used_p, "need unreserved growth for overcommit"
+        quota = used_p + lifetime
 
     pool = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
     eng = Engine(cfg, with_model_axis(params),
                  pool.register_model(cfg, quota), max_slots=2)
+    if cfg.family == "ssm":
+        _refuse_odd_positions_once(eng.view)
     mux = MuxScheduler({cfg.name: eng}, pool, policy="adbs")
     r1 = Request(0, cfg.name, list(p1), max_new)
     r2 = Request(1, cfg.name, list(p2), max_new)
     mux.submit(r1)
     mux.submit(r2)
+    SPANS.clear()
     stats = mux.run(max_ticks=600)
     assert len(stats.finished) == 2
     assert pool.allocator.used == 0
-    # outputs must match uncontended serving despite rollback/preempt
+    assert _held_rows(cfg.name) > 0
+    # outputs must match uncontended serving despite hold/preempt
     pool2 = UnifiedKVPool(50_000, cfg.hd, dtype=jnp.float32)
     eng2 = Engine(cfg, with_model_axis(params),
                   pool2.register_model(cfg, 50_000),
                   max_slots=2)
+    SPANS.clear()
     for r in (r1, r2):
         q = Request(9, cfg.name, list(r.prompt), max_new)
         eng2.prefill([q])
         while not q.done:
             eng2.decode()
         assert r.output == q.output, r.req_id
+    assert _held_rows(cfg.name) == 0
 
 
 def test_quota_regrant_for_oversized_head_request():

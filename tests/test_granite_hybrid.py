@@ -19,6 +19,7 @@ from repro.serving.engine import Engine, Request
 from repro.serving.kvcache import UnifiedKVPool
 from repro.core.workload import RequestSpec, Workload
 
+from _rows import serve_rows_out_of_order
 from _weights import with_model_axis
 
 CFG = configs.get_reduced("granite-4.0-h-small")
@@ -183,6 +184,29 @@ def test_engine_chunked_prefill_and_decode_match_the_reference():
 
 def test_bfloat16_engine_fails_the_tolerance():
     assert _worst_error(jnp.bfloat16) > TOLERANCE
+
+
+def test_steps_index_their_slots_out_of_order():
+    """Six requests in six slots; those in slots 0, 2 and 5 finish with
+    their prompt, so every later chunk and decode step runs 3 rows
+    (slots 4, 3, 1, in that order) in a bucket of 4.  Each step leaves
+    the other slots' state as it was; a chunk that starts its prompt
+    starts from zero state, whatever the slot held; every request's
+    tokens are the reference's greedy ones."""
+    params = init_params(jax.random.PRNGKey(3), CFG, jnp.float32)
+    pool = UnifiedKVPool(4096, CFG.hd, dtype=jnp.float32)
+    eng = Engine(CFG, with_model_axis(params), pool.register_model(CFG, 4096),
+                 max_slots=6, chunk_tokens=16)
+    rng = np.random.default_rng(5)
+    reqs = [Request(i, CFG.name, rng.integers(1, CFG.vocab_size, n).tolist(),
+                    m) for i, (n, m) in enumerate(zip((12, 40, 9, 35, 21, 14),
+                                                      (1, 6, 1, 5, 6, 1)))]
+    rows = serve_rows_out_of_order(eng, reqs)
+    assert 3 in rows and all(r.done for r in reqs)
+    for r in reqs:
+        ref = reference_logits(params, CFG, r.prompt + r.output[:-1])
+        assert r.output == ref[len(r.prompt) - 1:].argmax(-1).tolist(), \
+            r.req_id
 
 
 # ---------------------------------------------------------------------------

@@ -85,10 +85,9 @@ def test_span_tree_of_a_serving_loop_under_the_logical_clock():
     for r in roots:
         kids = [i for i in range(len(name)) if v.parent[i] == r]
         phases = [name[i][len(name[r]) + 1:] for i in kids]
-        # the SSM state's scatter commits before the host syncs
-        ssm = name[r].endswith(".s")
-        assert phases == (["prep", "launch", "commit", "sync", "commit"]
-                          if ssm else list(STEP_PHASES)), (name[r], phases)
+        # an SSM model's state is written inside its step: its steps
+        # have the same phases as an attention model's
+        assert phases == list(STEP_PHASES), (name[r], phases)
         by = Counter()
         for i, p in zip(kids, phases):
             by[p] += dur[i]

@@ -13,11 +13,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.config import BLOCK_TOKENS, replace
 from repro.configs.granite_4p0_h_small import CONFIG as GRANITE
+from repro.configs.mamba2_2p7b import CONFIG as MAMBA2
 from repro.configs.phi_3_vision_4p2b import CONFIG as PHI3
 from repro.kernels.flash_prefill import (flash_prefill,
                                          fused_paged_flash_prefill)
@@ -170,11 +172,53 @@ def test_step_writes_the_arena_in_place(chip, kind, hd):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
 
 
-# granite-4.0-h-small at published widths: one period of its layer
-# pattern (9 Mamba-2 layers, 1 attention layer), 9 of the 72 experts
-# held (one chip's share of eight), 16 rows over the benchmark cell's
-# arena (16 slots x 64 token-blocks x 2 attention layers x 8 KV heads)
+# models with SSM layers at published widths: mamba2-2.7b (4 of its 64
+# layers, the colocated cells' 2 slots) and granite-4.0-h-small (one
+# period of its layer pattern, 9 Mamba-2 layers and 1 attention layer;
+# 9 of the 72 experts held, one chip's share of eight; the backlog
+# cell's 16 slots over its arena, 16 slots x 64 token-blocks x 2
+# attention layers x 8 KV heads), each step's rows filling the slots
 GRANITE_ROWS, GRANITE_ARENA = 16, 16_384
+
+
+def _ssm_cfg(model):
+    if model == "mamba2":
+        return replace(MAMBA2, name="", n_layers=4), 2
+    return replace(GRANITE, name="", n_layers=10,
+                   layer_types=GRANITE.layer_types[:10],
+                   moe=replace(GRANITE.moe, held_count=9)), GRANITE_ROWS
+
+
+def _ssm_step(chip, model, kind, slots):
+    """Compile ``kind`` of ``model`` over a state of ``slots`` slots;
+    returns (compiled, the state's and the conv tail's shapes)."""
+    cfg, b = _ssm_cfg(model)
+    sc = cfg.ssm
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_stacked_params(k, cfg, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    shapes = ((cfg.n_ssm_layers, slots, cfg.n_ssm_heads, sc.head_dim,
+               sc.d_state),
+              (cfg.n_ssm_layers, slots, (sc.conv_kernel - 1)
+               * (cfg.d_inner + 2 * sc.n_groups * sc.d_state)))
+    state = (spec(shapes[0], jnp.float32), spec(shapes[1], jnp.bfloat16))
+    pool = spec((GRANITE_ARENA, *arena_block_shape(BLOCK_TOKENS, 128)),
+                jnp.bfloat16)
+    i32 = jnp.int32
+    table = spec((b, STEP_TABLE + 1), i32)      # the slots' column last
+    if kind == "decode":
+        args = (spec((b,), i32), spec((b,), i32), pool, pool, table)
+    else:
+        rows = (spec((b, STEP_CHUNK), i32), spec((b,), i32), spec((b,), i32))
+        args = rows + ((table,) if model == "mamba2" else (pool, pool, table))
+        kind = "chunk_ssm" if model == "mamba2" else "chunk"
+    compiled = jitted_step(kind, cfg).lower(
+        params, spec((), i32), *args, *state).compile()
+    return compiled, shapes
 
 
 @pytest.mark.parametrize("kind", ["decode", "chunk"])
@@ -184,36 +228,43 @@ def test_granite_step_gathers_no_expert_weights_per_token(chip, kind):
     experts' weights ([T, k, d, f_e]), and a decode step's temporaries
     are below one layer's held experts (a layer's slice of the stacked
     expert weights is never copied out for the grouped matmul)."""
-    cfg = replace(GRANITE, name="", n_layers=10,
-                  layer_types=GRANITE.layer_types[:10],
-                  moe=replace(GRANITE.moe, held_count=9))
-    mc, sc, b = cfg.moe, cfg.ssm, GRANITE_ROWS
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-    params = jax.tree_util.tree_map(
-        lambda a: spec(a.shape, a.dtype),
-        jax.eval_shape(lambda k: init_stacked_params(k, cfg, jnp.bfloat16),
-                       jax.random.PRNGKey(0)))
-    pool = spec((GRANITE_ARENA, *arena_block_shape(BLOCK_TOKENS, cfg.hd)),
-                jnp.bfloat16)
-    i32 = jnp.int32
-    state = (spec((cfg.n_ssm_layers, b, cfg.n_ssm_heads, sc.head_dim,
-                   sc.d_state), jnp.float32),
-             spec((cfg.n_ssm_layers, b, sc.conv_kernel - 1,
-                   cfg.d_inner + 2 * sc.n_groups * sc.d_state),
-                  jnp.bfloat16))
-    table = spec((b, STEP_TABLE), i32)
-    if kind == "decode":
-        tokens = b
-        args = (spec((b,), i32), spec((b,), i32), pool, pool, table)
-    else:
-        tokens = b * STEP_CHUNK
-        args = (spec((b, STEP_CHUNK), i32), spec((b,), i32),
-                spec((b,), i32), pool, pool, table)
-    compiled = jitted_step(kind, cfg).lower(
-        params, spec((), i32), *args, *state).compile()
+    cfg, b = _ssm_cfg("granite")
+    mc = cfg.moe
+    compiled, _ = _ssm_step(chip, "granite", kind, b)
+    tokens = b if kind == "decode" else b * STEP_CHUNK
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < tokens * mc.top_k * cfg.d_model * mc.d_expert * 2
     if kind == "decode":
         assert temp < mc.n_held * 3 * cfg.d_model * mc.d_expert * 2
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+@pytest.mark.parametrize("model", ["mamba2", "granite"])
+def test_step_writes_the_ssm_state_in_place(chip, model, kind):
+    """A step of a model with SSM layers takes the whole SSM state and
+    conv tail and writes its rows' slots where they lie: each appears
+    only as a parameter, never copied, in one layout; the program
+    returns it aliased to its input; and its temporaries do not grow
+    with the slots the state holds (a decode step's stay far below the
+    state's size)."""
+    cfg, b = _ssm_cfg(model)
+    compiled, shapes = _ssm_step(chip, model, kind, b)
+    text = compiled.as_text()
+    for shape in shapes:
+        dims = ",".join(map(str, shape))
+        op = re.compile(rf"= \w+\[{dims}\]\{{([\d,]*)\S* (copy|parameter)\(")
+        ops = [m.groups() for m in map(op.search, text.splitlines()) if m]
+        assert ops and all(o == "parameter" for _, o in ops), ops
+        assert len({lay for lay, _ in ops}) == 1, ops
+    aliased = {int(i) for i in re.findall(r"\((\d+), \{\}, \w+-alias\)",
+                                          text.splitlines()[0])}
+    params = dict(re.findall(r"parameter\((\d+)\).*op_name=\"(ssm_state|"
+                             r"conv_tail)\"", text))
+    assert len(params) == 2 and {int(i) for i in params} <= aliased, params
+    state_bytes = 4 * int(np.prod(shapes[0]))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    wider, _ = _ssm_step(chip, model, kind, 2 * b)
+    assert wider.memory_analysis().temp_size_in_bytes - temp \
+        < state_bytes / 8
+    if kind == "decode":
+        assert temp < state_bytes / 4
